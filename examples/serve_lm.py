@@ -2,6 +2,8 @@
 
 Run:  PYTHONPATH=src python examples/serve_lm.py [--arch gemma3_12b]
 """
+# This parent only builds the command line and never imports jax: a TPU
+# chip belongs to one process at a time, and the child must get it.
 import argparse
 import subprocess
 import sys

@@ -6,6 +6,8 @@ model for a few hundred steps (sized for a TPU host; takes hours on 1 CPU).
 
 Run:  PYTHONPATH=src python examples/train_lm.py [--preset 100m] [--steps N]
 """
+# This parent only builds the command line and never imports jax: a TPU
+# chip belongs to one process at a time, and the child must get it.
 import argparse
 import subprocess
 import sys

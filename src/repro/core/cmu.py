@@ -57,6 +57,7 @@ from .dataflow import (
     best_kernel_dataflow,
     hbm_traffic_bytes,
     kernel_block_candidates,
+    revisits_output,
     scan_decode_traffic_bytes,
     scan_traffic_bytes,
     strip_blocks,
@@ -620,6 +621,8 @@ def measure_kernel(
     (dataflow, block, strip) — interpret mode on CPU, on-device on TPU.
 
     Returns the best of ``iters`` timed runs (min filters scheduler noise).
+    Operands are ``dtype`` (default bf16, the width the traffic and VMEM
+    models price and the models' compute dtype).
     ``epilogue`` selects what is timed for forward GEMMs: ``False`` the bare
     matmul, ``True`` the legacy bias+gelu probe, or an ``EpilogueSig`` for
     the layer's actual fused signature (so the measurement covers the op the
@@ -651,7 +654,7 @@ def measure_kernel(
 
     if interpret is None:
         interpret = ops.default_interpret()
-    dtype = dtype or jnp.float32
+    dtype = dtype or jnp.bfloat16
     sig = _epilogue_sig(epilogue)
     if sig is not None and (trans != NO_TRANS or via_copy):
         raise ValueError(
@@ -752,7 +755,8 @@ def measure_quant_error(gemm: GemmShape, qdtype: str) -> float:
 
 
 def _ranked_candidates(
-    gemm: GemmShape, vmem_limit: int, quant: tuple[str, ...] = ()
+    gemm: GemmShape, vmem_limit: int, quant: tuple[str, ...] = (),
+    *, on_chip: bool = False,
 ) -> list[tuple[float, Dataflow, tuple[int, int, int], int, str | None]]:
     """All VMEM-feasible (dataflow, block, strip) configs, best analytical
     first.
@@ -774,6 +778,10 @@ def _ranked_candidates(
     f32 per-channel scale.  Pass the eligible dtypes sorted by calibration
     error: the sort is stable, so when two 1-byte dtypes tie on traffic the
     lower-error one ranks first.
+
+    ``on_chip`` (the kernels will be compiled by Mosaic, not interpreted)
+    leaves out the streamed WS/IS schedules that revisit their partial-sum
+    blocks (``revisits_output``): only the interpreter runs them.
     """
     ranked = []
     for df in ALL_DATAFLOWS:
@@ -783,6 +791,8 @@ def _ranked_candidates(
                     for strip in strip_candidates(
                         strip_blocks(gemm, df, bm, bn)
                     ):
+                        if on_chip and revisits_output(df, gemm.K, bk, strip):
+                            continue
                         for qd in (None, *quant):
                             # explicit per-operand widths — the byte model
                             # must not fall back to a silent dtype default
@@ -845,7 +855,8 @@ def _tune_gemm(
         qerrs = {qd: measure_quant_error(gemm, qd) for qd in quant}
         eligible = tuple(sorted((qd for qd in quant if qerrs[qd] <= budget),
                                 key=lambda qd: qerrs[qd]))
-    ranked = _ranked_candidates(gemm, vmem_limit, quant=eligible)
+    ranked = _ranked_candidates(gemm, vmem_limit, quant=eligible,
+                                on_chip=not interpret)
     if not ranked:
         raise ValueError(f"no (dataflow, block, strip) fits VMEM for {gemm}")
     fallback = "bf16" if quant else None
@@ -990,7 +1001,7 @@ def measure_attention(
 
     if interpret is None:
         interpret = ops.default_interpret()
-    dtype = dtype or jnp.float32
+    dtype = dtype or jnp.bfloat16
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(kq, (1, shape.seq, shape.heads, shape.head_dim), dtype)
     k = jax.random.normal(kk, (1, shape.kv, shape.kv_heads, shape.head_dim), dtype)
@@ -1036,7 +1047,7 @@ def measure_attention_decode(
 
     if interpret is None:
         interpret = ops.default_interpret()
-    dtype = dtype or jnp.float32
+    dtype = dtype or jnp.bfloat16
     cache_len = max(min(shape.kv, 64), block_size)
     nb = -(-cache_len // block_size)
     kq, kp = jax.random.split(jax.random.PRNGKey(0))
@@ -1222,7 +1233,7 @@ def measure_scan(
 
     if interpret is None:
         interpret = ops.default_interpret()
-    dtype = dtype or jnp.float32
+    dtype = dtype or jnp.bfloat16
     seq = -(-shape.seq // chunk) * chunk  # the padded T the model dispatches
     r, k, v, lw, u = _scan_inputs(shape, seq, dtype)
     run = lambda: flex_scan(r, k, v, lw, u, chunk=chunk, sweep=sweep,
@@ -1263,7 +1274,7 @@ def measure_scan_decode(
 
     if interpret is None:
         interpret = ops.default_interpret()
-    dtype = dtype or jnp.float32
+    dtype = dtype or jnp.bfloat16
     bshape = ScanShape(batch=bucket, seq=1, heads=shape.heads,
                        key_dim=shape.key_dim, val_dim=shape.val_dim,
                        post_update=shape.post_update)
@@ -1308,7 +1319,10 @@ def _tune_scan(
     buckets are requested."""
     ranked = []
     seen = set()
-    for sweep in SCAN_SWEEPS:
+    # the "out" sweep revisits its state blocks non-consecutively, which
+    # only the interpreter runs (see ``dataflow.revisits_output``)
+    sweeps = SCAN_SWEEPS if interpret else ("state",)
+    for sweep in sweeps:
         for chunk in SCAN_CHUNK_CANDIDATES:
             # dedup schedules whose padded grid collapses to one chunk
             eff = (sweep, min(chunk, -(-shape.seq // 8) * 8))

@@ -154,11 +154,41 @@ def best_dataflow(shape: GemmShape, rows: int, cols: int) -> tuple[Dataflow, int
 # TPU-native (kernel-level) cost model: HBM <-> VMEM block traffic.
 # ---------------------------------------------------------------------------
 
-# The single VMEM budget every planner and feasibility check shares: the
-# analytical pruning, the measured autotune, and the strip-feasibility check
-# all answer to this one constant (a conservative per-core figure — block
-# working sets plus the f32 accumulator strip must fit under it).
+# The single VMEM budget: every flex ``pallas_call`` passes it to Mosaic as
+# ``vmem_limit_bytes``, and every planner and feasibility check (analytical
+# pruning, measured autotune, strip feasibility, attention and scan
+# schedules) admits only schedules whose modelled working set fits under
+# it.  A v5e core has 128 MiB of VMEM; 96 MiB leaves the rest to Mosaic's
+# internal scratch.  The working-set models below count what the compiler
+# allocates, as an upper bound: every input and output block twice (the
+# Pallas pipeline double-buffers each), scratch once, the kernel's f32
+# temporaries, and each buffer padded to Mosaic's (sublane, 128-lane) tile
+# (``vmem_tile_bytes``), so a schedule the model admits compiles.
 VMEM_BUDGET_BYTES = 96 * 1024 * 1024
+
+
+def vmem_tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes one ``(rows, cols)`` VMEM buffer occupies as Mosaic lays it
+    out: rows padded to the dtype's sublane tile (8 rows of 32-bit, 16 of
+    16-bit, 32 of 8-bit values), columns to the 128-lane vreg width."""
+    sublanes = 32 // min(itemsize, 4)
+    return (_ceil_div(rows, sublanes) * sublanes
+            * _ceil_div(cols, 128) * 128 * itemsize)
+
+
+def revisits_output(dataflow: Dataflow, K: int, bk: int,
+                    strip: int = 1) -> bool:
+    """Whether a GEMM schedule revisits an output block non-consecutively:
+    the streamed WS/IS schedule (``strip=1``) with K over more than one
+    block comes back to every partial-sum block once per k plane.
+
+    The Pallas interpreter reads such a block back from HBM.  Mosaic does
+    not: it writes an output block back when the grid moves off it and
+    never reads it in, so on a TPU the partial sums of earlier k planes are
+    lost.  Planners offer these schedules only when the kernels run in the
+    interpreter, and the kernel builder refuses them on the chip."""
+    return (dataflow is not Dataflow.OS and strip == 1
+            and _ceil_div(K, bk) > 1)
 
 
 @dataclass(frozen=True)
@@ -217,13 +247,9 @@ def hbm_traffic_bytes(
       WS strip: bytes = ceil(Mb/strip) * (K*N) * in + Nb * (M*K) * in + c
       IS strip: bytes = ceil(Nb/strip) * (M*K) * in + Mb * (K*N) * in + c
 
-    and the VMEM working set grows by the strip's resident output buffers:
-    the f32 accumulator strip plus the same-extent copy-out block the
-    fused kernels allocate, ``strip * bm * bn * (4 + out_bytes)`` (an
-    over-count for the plain-f32 case, where the two share one buffer —
-    conservative on purpose: a strip the budget admits must actually fit).
-    ``strip=1`` is exactly the streamed schedule above; OS ignores
-    ``strip`` (its accumulator is already VMEM-resident, and the strip
+    and the VMEM working set grows by the strip's resident output buffers
+    (``gemm_vmem_bytes``).  ``strip=1`` is exactly the streamed schedule
+    above; OS ignores ``strip`` (its accumulator is already VMEM-resident, and the strip
     generalisation of OS *is* the IS strip schedule).
 
     **Per-operand dtypes.**  ``in_bytes`` is the legacy both-operands
@@ -233,6 +259,8 @@ def hbm_traffic_bytes(
     with the B operand — folded into the B term so every refetch factor
     multiplies it too, and into the VMEM working set as one ``bn``-wide
     row per resident B block.
+
+    ``vmem_bytes`` is ``gemm_vmem_bytes`` of the schedule.
     """
     M, K, N = shape.M, shape.K, shape.N
     if a_bytes is None:
@@ -243,30 +271,67 @@ def hbm_traffic_bytes(
     a = M * K * a_bytes
     b = K * N * b_bytes + N * scale_bytes
     c = M * N * out_bytes
-    blocks_vmem = bm * bk * a_bytes + bk * bn * b_bytes + bn * scale_bytes
     if dataflow is Dataflow.OS:
         hbm = Nb * a + Mb * b + c
-        vmem = blocks_vmem + bm * bn * 4  # f32 accumulator
     elif dataflow is Dataflow.WS:
         if strip > 1:
             hbm = _ceil_div(Mb, strip) * b + Nb * a + c
-            # f32 accumulator strip + the fused kernels' copy-out strip
-            vmem = blocks_vmem + strip * bm * bn * (4 + out_bytes)
         else:
             partial_rw = (2 * Kb - 1) * c if Kb > 1 else c
             hbm = b + Nb * a + partial_rw
-            vmem = blocks_vmem + bm * bn * 4
     elif dataflow is Dataflow.IS:
         if strip > 1:
             hbm = _ceil_div(Nb, strip) * a + Mb * b + c
-            vmem = blocks_vmem + strip * bm * bn * (4 + out_bytes)
         else:
             partial_rw = (2 * Kb - 1) * c if Kb > 1 else c
             hbm = a + Mb * b + partial_rw
-            vmem = blocks_vmem + bm * bn * 4
     else:  # pragma: no cover
         raise ValueError(dataflow)
+    vmem = gemm_vmem_bytes(dataflow, bm, bk, bn, strip, a_bytes=a_bytes,
+                           b_bytes=b_bytes, out_bytes=out_bytes,
+                           scale_bytes=scale_bytes)
     return KernelCost(hbm_bytes=hbm, mxu_flops=shape.flops, vmem_bytes=vmem)
+
+
+def gemm_vmem_bytes(dataflow: Dataflow, bm: int, bk: int, bn: int,
+                    strip: int = 1, *, a_bytes: int = 2, b_bytes: int = 2,
+                    out_bytes: int = 4, scale_bytes: int = 0) -> int:
+    """Upper bound on the VMEM one flex-GEMM ``pallas_call`` allocates.
+
+    The output block is ``(bm, bn)``, or the strip's ``(strip*bm, bn)``
+    (WS) / ``(bm, strip*bn)`` (IS).  Double-buffered inputs: the A and B
+    blocks (each in whichever of its two layouts pads larger, since
+    backward GEMMs read transposed operands), and the epilogue operands a
+    fused call may carry: the quant scale row, the bias row and the
+    residual block.  Double-buffered outputs: the finished block plus an
+    f32 block of the same extent (the streamed schedules' partial-sum
+    staging buffer, or the saved pre-activation of a training forward).
+    Once: OS's f32 accumulator scratch (the strip's scratch is covered by
+    the f32 output term, which it replaces when the pre-activation is
+    saved).  Temporaries: three f32 ``(bm, bn)`` values, the block product
+    and the epilogue's pre-activation and result, and for a quantized B
+    (``b_bytes != a_bytes``) the f32 copies of both operand blocks the
+    mixed-dtype product is computed on, twice: a v5e has no fp8 unit, and
+    Mosaic widens an fp8 block through a 32-bit intermediate of the same
+    extent (int8 needs one copy; the bound covers both 1-byte dtypes).
+    """
+    if strip > 1 and dataflow is Dataflow.WS:
+        rows, cols = strip * bm, bn
+    elif strip > 1 and dataflow is Dataflow.IS:
+        rows, cols = bm, strip * bn
+    else:
+        rows, cols = bm, bn
+    t = vmem_tile_bytes
+    ins = (max(t(bm, bk, a_bytes), t(bk, bm, a_bytes))
+           + max(t(bk, bn, b_bytes), t(bn, bk, b_bytes))
+           + (t(1, cols, scale_bytes) if scale_bytes else 0)
+           + t(1, cols, out_bytes) + t(rows, cols, out_bytes))
+    outs = t(rows, cols, out_bytes) + t(rows, cols, 4)
+    scratch = t(bm, bn, 4) if dataflow is Dataflow.OS else 0
+    temps = 3 * t(bm, bn, 4)
+    if b_bytes != a_bytes:
+        temps += 2 * (t(bm, bk, 4) + t(bk, bn, 4))
+    return 2 * ins + 2 * outs + scratch + temps
 
 
 def strip_blocks(shape: GemmShape, dataflow: Dataflow, bm: int, bn: int) -> int:
@@ -449,12 +514,17 @@ def attn_traffic_bytes(shape: AttnShape, sweep: str, bq: int, bk: int,
 
       q-stationary:  q + o move once; K/V re-stream once per q tile:
           hbm  = q_bytes + nq * kv_bytes + o_bytes
-          vmem = (bq + 2*bk) * hd * in + bq * hd * 4 + 2 * bq * 4
       kv-stationary: K/V move once; q re-streams once per kv tile, and the
-      whole-rows accumulator slab (f32 acc + copy-out + m/l stats) is
-      VMEM-resident so the output flushes exactly once:
+      whole-rows accumulator slab (f32 acc + m/l stats) is VMEM-resident
+      so the output flushes exactly once:
           hbm  = kv_bytes + nkv * q_bytes + o_bytes
-          vmem = (bq + 2*bk) * hd * in + rows * hd * (4 + out) + 2 * rows * 4
+
+    VMEM, as the compiler allocates it: the q, k, v blocks and the output
+    block (``(bq, hd)``, or the whole ``(rows, hd)`` rows for
+    kv-stationary) double-buffered; the f32 accumulator and the m/l stats
+    (one lane-padded column each) over the same rows; the f32 copies of
+    the q, k, v blocks the kernel computes in and the ``(bq, bk)`` score
+    and probability tiles.
 
     The kv-stationary HBM win scales with ``nq = rows / bq`` — i.e. with
     the GQA group and context length — which is exactly the paper's
@@ -468,13 +538,17 @@ def attn_traffic_bytes(shape: AttnShape, sweep: str, bq: int, bk: int,
     q_bytes = rows * hd * in_bytes
     kv_bytes = 2 * kv * hd * in_bytes
     o_bytes = rows * hd * out_bytes
-    blocks_vmem = (bq + 2 * bk) * hd * in_bytes
+    t = vmem_tile_bytes
     if sweep == "q":
         hbm = shape.kv_heads * (q_bytes + nq * kv_bytes + o_bytes)
-        vmem = blocks_vmem + bq * hd * 4 + 2 * bq * 4
+        out_rows = bq
     else:
         hbm = shape.kv_heads * (kv_bytes + nkv * q_bytes + o_bytes)
-        vmem = blocks_vmem + rows * hd * (4 + out_bytes) + 2 * rows * 4
+        out_rows = rows
+    vmem = (2 * (t(bq, hd, in_bytes) + 2 * t(bk, hd, in_bytes)
+                 + t(out_rows, hd, out_bytes))
+            + t(out_rows, hd, 4) + 2 * t(out_rows, 1, 4)
+            + t(bq, hd, 4) + 2 * t(bk, hd, 4) + 2 * t(bq, bk, 4))
     return KernelCost(hbm_bytes=hbm, mxu_flops=shape.flops, vmem_bytes=vmem)
 
 
@@ -540,12 +614,15 @@ def scan_traffic_bytes(shape: ScanShape, sweep: str, chunk: int,
       state-stationary: the whole ``bh*N*M`` f32 slab is a never-moving
       output block — VMEM-resident across the grid, written once:
           hbm  = streams + state_bytes
-          vmem = blocks + state_bytes
       output-stationary: the state is a per-(b,h) block revisited
       non-consecutively across the chunk axis, so it round-trips HBM every
       chunk step (read-modify-write), and VMEM holds just one block:
           hbm  = streams + 2 * C * state_bytes
-          vmem = blocks + 2 * N * M * 4
+
+    VMEM, as the compiler allocates it: one step's r/k/v/log_w/u input
+    blocks, the o block and the state block (the whole slab, or one
+    ``(N, M)`` block) double-buffered, plus the step's f32 temporaries
+    (compute copies of the tiles, the ``(L, L)`` score tile, the new state).
 
     The state-stationary HBM win scales with C = seq/chunk; its VMEM cost
     scales with ``batch*heads*N*M`` — which is exactly the paper's
@@ -564,14 +641,17 @@ def scan_traffic_bytes(shape: ScanShape, sweep: str, chunk: int,
     v_bytes = T * m * in_bytes
     o_bytes = T * m * out_bytes
     streams = shape.bh * (rk_bytes + lw_bytes + v_bytes + o_bytes)
-    # one grid step's tile set (f32 compute copies + the (L, L) score tile)
-    blocks = (3 * L * n + L * m) * 4 + L * L * 4 + L * m * 4 + n * m * 4
+    t = vmem_tile_bytes
+    ins = (2 * t(L, n, in_bytes) + t(L, m, in_bytes) + t(L, n, 4)
+           + t(1, n, 4))
+    temps = (3 * t(L, n, 4) + 2 * t(L, m, 4) + t(L, L, 4) + t(n, m, 4))
     if sweep == "state":
         hbm = streams + shape.state_bytes
-        vmem = blocks + shape.state_bytes
+        state_blk = t(shape.bh * n, m, 4)
     else:
         hbm = streams + 2 * C * shape.state_bytes
-        vmem = blocks + 2 * n * m * 4
+        state_blk = t(n, m, 4)
+    vmem = 2 * (ins + t(L, m, out_bytes) + state_blk) + temps
     return KernelCost(hbm_bytes=hbm, mxu_flops=shape.flops, vmem_bytes=vmem)
 
 
@@ -626,9 +706,15 @@ def attn_decode_traffic_bytes(shape: AttnShape, kind: str, bucket: int,
     flops = 4 * bucket * shape.heads * kv * hd
     if kind == "paged":
         hbm = q_bytes + cache_bytes + o_bytes
-        vmem = (shape.heads * hd * in_bytes
-                + 2 * block_size * hkv * hd * in_bytes
-                + shape.heads * hd * 4 + 2 * shape.heads * 4)
+        # double-buffered (H, hd) q/o blocks and (bs, Hkv, hd) K/V blocks,
+        # the (Hkv, group, .) f32 accumulator and m/l scratch, and the f32
+        # score/probability tiles
+        t = vmem_tile_bytes
+        group = shape.group
+        vmem = (2 * (t(shape.heads, hd, in_bytes) + t(shape.heads, hd, out_bytes)
+                     + 2 * block_size * t(hkv, hd, in_bytes))
+                + hkv * (t(group, hd, 4) + 2 * t(group, 1, 4)
+                         + 2 * t(group, block_size, 4)))
     else:
         hbm = q_bytes + 3 * cache_bytes + o_bytes
         vmem = (shape.heads * hd + 2 * kv * hkv * hd) * in_bytes

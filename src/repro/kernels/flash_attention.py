@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flex_matmul import CompilerParams, _VMEM
+from repro.kernels.flex_matmul import _VMEM, compiler_params
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -230,7 +230,7 @@ def flex_attention(q, k, v, *, sweep: str = "q", causal: bool = True,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, hd), q.dtype),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(dimension_semantics=semantics),
+        compiler_params=compiler_params(*semantics),
         interpret=interpret,
     )(q, k, v)
 
@@ -395,8 +395,7 @@ def paged_attention(q, pool_k, pool_v, table, positions, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(table, positions, q, pool_k, pool_v)
 

@@ -141,14 +141,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.dataflow import Dataflow
+from repro.core.dataflow import VMEM_BUDGET_BYTES, Dataflow, revisits_output
 
 DEFAULT_BLOCK = (256, 256, 256)  # (bm, bk, bn) — MXU-aligned, ~768KB working set
 
-# jax 0.4.x names these TPUCompilerParams / VMEM; newer releases renamed them
-# to CompilerParams / MemorySpace.VMEM.  Resolve whichever exists once.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-_VMEM = getattr(getattr(pltpu, "MemorySpace", None), "VMEM", None) or pltpu.VMEM
+_VMEM = pltpu.MemorySpace.VMEM
+
+
+def compiler_params(*semantics: str) -> pltpu.CompilerParams:
+    """Mosaic parameters shared by every flex kernel: the grid's dimension
+    semantics, and ``VMEM_BUDGET_BYTES`` — the budget the CMU plans
+    against — as the kernel's scoped-VMEM limit, so every schedule the
+    feasibility model admits is one the compiler accepts."""
+    return pltpu.CompilerParams(dimension_semantics=semantics or None,
+                                vmem_limit_bytes=VMEM_BUDGET_BYTES)
 
 
 # ---------------------------------------------------------------------------
@@ -630,9 +636,7 @@ def matmul_os(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[_VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(a, b, *extra)
     return (result[0], result[1]) if save_preact else result
@@ -677,6 +681,12 @@ def _matmul_stream(
             interpret=interpret, save_preact=save_preact,
             trans_a=trans_a, trans_b=trans_b, strip=strip, qscale=qscale,
         )
+    if not interpret and revisits_output(Dataflow.WS, K, bk):
+        raise ValueError(
+            f"streamed {stationary}-stationary schedule with K={K} over "
+            f"{K // bk} blocks revisits its partial-sum blocks, which Mosaic "
+            "never reads back: on the chip use bk=K or an accumulator strip"
+        )
     grid, a_map, b_map, c_map, bias_map = _stream_schedule(
         stationary, M // bm, K // bk, N // bn
     )
@@ -710,9 +720,7 @@ def _matmul_stream(
         in_specs=[a_spec, b_spec, *extra_specs],
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
-        ),
+        compiler_params=compiler_params("arbitrary", "arbitrary", "arbitrary"),
         interpret=interpret,
     )(a, b, *extra)
     out = result[1] if fused else result
@@ -797,12 +805,10 @@ def _matmul_strip(
         out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
         out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
-            # (s, j/i) own disjoint output strips — single-writer, so
-            # megacore partitioning can engage; k and u stay sequential
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary")
-        ),
+        # (s, j/i) own disjoint output strips — single-writer, so
+        # megacore partitioning can engage; k and u stay sequential
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary",
+                                        "arbitrary"),
         interpret=interpret,
     )(a, b, *extra)
     if save_preact:

@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.flex_matmul import CompilerParams
+from repro.kernels.flex_matmul import compiler_params
 
 #: Chunk-grid sweep orders (where the running state lives).
 SCAN_SWEEPS = ("state", "out")
@@ -189,6 +189,10 @@ def flex_scan(
         from repro.kernels import ops
 
         interpret = ops.default_interpret()
+    if sweep == "out" and not interpret:
+        raise ValueError(
+            "the 'out' sweep revisits its state blocks across chunks, which "
+            "Mosaic never reads back: on the chip use sweep='state'")
     o, S = pl.pallas_call(
         functools.partial(_scan_kernel, sweep=sweep,
                           post_update=post_update, n=N),
@@ -198,8 +202,7 @@ def flex_scan(
                    s_spec],
         out_shape=[jax.ShapeDtypeStruct((BH, C, L, M), v.dtype),
                    jax.ShapeDtypeStruct((BH * N, M), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+        compiler_params=compiler_params("arbitrary", "arbitrary"),
         interpret=interpret,
     )(*inputs)
     o = jnp.moveaxis(o.reshape(B, H, T, M), 1, 2)
@@ -265,6 +268,7 @@ def flex_recurrent_step(
         functools.partial(_step_kernel, post_update=post_update),
         out_shape=[jax.ShapeDtypeStruct((BH, M), v.dtype),
                    jax.ShapeDtypeStruct((BH, N, M), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(*inputs)
     return o.reshape(B, H, M), S_new.reshape(B, H, N, M)

@@ -60,7 +60,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.cmu import MeshPlan, mesh_local_gemm
 from repro.core.dataflow import Dataflow, GemmShape, best_kernel_dataflow
 from repro.core.dist_dataflow import best_mesh_dataflow
-from repro.launch.mesh import dp_size, shard_map
+from repro.launch.mesh import dp_size
 
 from . import flex_matmul as fk
 from . import ops
@@ -214,7 +214,7 @@ def flex_linear_sharded(
         r_l = next(it) if residual is not None else None
         return body(x_l, w_l, b_l, r_l)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=tok_spec,
-        check_rep=False,
+        check_vma=False,
     )(*args)

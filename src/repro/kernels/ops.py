@@ -57,7 +57,12 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.dataflow import Dataflow, GemmShape, best_kernel_dataflow
+from repro.core.dataflow import (
+    Dataflow,
+    GemmShape,
+    best_kernel_dataflow,
+    revisits_output,
+)
 
 from . import flex_matmul as fk
 from .quantize import QDTYPES, quantize_channel
@@ -126,6 +131,23 @@ def _fit_strip(dataflow: Dataflow, strip: int, M: int, N: int,
     return s
 
 
+def _fit_schedule(dataflow: Dataflow, strip: int, M: int, K: int, N: int,
+                  block: tuple[int, int, int], interpret: bool):
+    """The (dataflow, block, strip) a GEMM dispatches: the block fitted to
+    the padded dims (``_fit_block``), the strip clamped to what they admit
+    (``_fit_strip``).  Compiled for the chip, a WS/IS schedule left with a
+    strip of 1 and more than one k block — a trace-time fallback, or a plan
+    tuned at a longer M applied to a shorter one — runs as OS at the same
+    block: Mosaic never reads a revisited partial-sum block back
+    (``dataflow.revisits_output``), and OS keeps the block's accumulator in
+    VMEM across k.  Every dataflow computes the same bits."""
+    blk = _fit_block(M, K, N, block)
+    strip = _fit_strip(dataflow, strip, M, N, blk)
+    if not interpret and revisits_output(dataflow, K, blk[1], strip):
+        dataflow = Dataflow.OS
+    return dataflow, blk, strip
+
+
 def _bwd_choice(spec: BwdSpec | None, M: int, K: int, N: int,
                 default_trans: tuple[bool, bool] = (False, False)):
     """Resolve one backward GEMM's (dataflow, block, trans, strip): the CMU
@@ -165,8 +187,8 @@ def _matmul_run(a, b, dataflow, block, interpret, out_dtype,
     saved full-precision operands, so the quant path never needs trans).
     """
     M, K, N = fk._logical_dims(a, b, trans_a, trans_b)
-    bm, bk, bn = _fit_block(M, K, N, block)
-    strip = _fit_strip(dataflow, strip, M, N, (bm, bk, bn))
+    dataflow, (bm, bk, bn), strip = _fit_schedule(dataflow, strip, M, K, N,
+                                                  block, interpret)
     if qdtype in QDTYPES:
         if trans_a or trans_b:
             raise ValueError(
@@ -291,8 +313,8 @@ def _linear_run(cfg: _LinearCfg, x, w, b, residual, save_preact: bool):
     """Primal fused linear; returns (out, z) with z=None unless save_preact."""
     M, K = x.shape
     _, N = w.shape
-    bm, bk, bn = _fit_block(M, K, N, cfg.block)
-    strip = _fit_strip(cfg.dataflow, cfg.strip, M, N, (bm, bk, bn))
+    dataflow, (bm, bk, bn), strip = _fit_schedule(
+        cfg.dataflow, cfg.strip, M, K, N, cfg.block, cfg.interpret)
     odt = cfg.out_dtype or jnp.promote_types(x.dtype, w.dtype)
     qscale = None
     if cfg.qdtype in QDTYPES:
@@ -305,7 +327,7 @@ def _linear_run(cfg: _LinearCfg, x, w, b, residual, save_preact: bool):
     bp = None if b is None else _pad_to(b.reshape(1, N), 1, bn)
     rp = None if residual is None else _pad_to(residual, bm, bn)
     out = fk.fused_matmul(
-        xp, wp, cfg.dataflow,
+        xp, wp, dataflow,
         bias=bp, residual=rp, activation=cfg.activation, out_dtype=odt,
         block=(bm, bk, bn), interpret=cfg.interpret, save_preact=save_preact,
         strip=strip, qscale=qscale,

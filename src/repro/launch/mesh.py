@@ -7,28 +7,26 @@ touches jax device state — the dry-run sets XLA_FLAGS before first jax use.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-# jax promoted shard_map out of experimental at 0.5; the pinned 0.4.x only
-# has the experimental spelling.  Every caller (models, runtime, tests)
-# imports this compat name instead of touching jax.shard_map directly.
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # jax < 0.5
-    from jax.experimental.shard_map import shard_map
-
-__all__ = ["dp_axes", "dp_size", "make_mesh", "make_production_mesh", "shard_map"]
+__all__ = ["dp_axes", "dp_size", "make_mesh", "make_production_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 chips per pod; the multi-pod mesh adds a leading DCN 'pod' axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (elastic resizes, CI-scale meshes)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (elastic resizes, CI-scale meshes).
+
+    Every axis is ``AxisType.Auto``: the models place activations with
+    ``with_sharding_constraint`` and compose kernels under ``jax.shard_map``,
+    which is auto-mode sharding (``jax.make_mesh`` defaults to Explicit).
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

@@ -493,10 +493,13 @@ class ServeScheduler:
                 poisoned = faults.pick_poison(step, len(slots))
                 if poisoned is not None:
                     poison[poisoned] = True
+            # host copies: the step runs asynchronously, and on the CPU
+            # backend jnp.asarray aliases the numpy buffer, which the
+            # bookkeeping below mutates before the step may have read it
             tok_b, ok_b, pool_k, pool_v = self._decode(
                 self.params, pool_k, pool_v,
-                jnp.asarray(tables[:b]), jnp.asarray(positions[:b]), tok[:b],
-                jnp.asarray(poison))
+                jnp.asarray(tables[:b].copy()), jnp.asarray(positions[:b].copy()),
+                tok[:b], jnp.asarray(poison))
             tok = tok.at[:b].set(tok_b)
             step += 1
             stats.steps += 1

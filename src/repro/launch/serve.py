@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.scheduler import (
     RequestStatus,
     ServeScheduler,
@@ -70,7 +71,7 @@ def sequential_reference(model, params, requests, cache_len: int):
     return out
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_4b")
     ap.add_argument("--smoke", action="store_true")
@@ -138,16 +139,30 @@ def main() -> None:
                     help="'DxM' data x model mesh (e.g. 2x4): serve "
                          "multi-device — projections run the shard_map-"
                          "composed mesh-native kernel path when --pallas")
-    args = ap.parse_args()
-    logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
+    return ap.parse_args(argv)
 
-    cfg = get_config(args.arch, smoke=args.smoke)
+
+def serve_config(args: argparse.Namespace):
+    """The model config a serving run uses.  Weights are held in bf16 from
+    creation on: serving never updates them, so an f32 master copy is
+    memory (17.6 GB for qwen3_4b, more than a v5e holds) and an f32->bf16
+    cast in every step."""
+    cfg = get_config(args.arch, smoke=args.smoke).replace(
+        param_dtype="bfloat16")
     if args.pallas:
         cfg = cfg.replace(use_pallas=True)
     if args.attn_pallas:
         cfg = cfg.replace(attn_pallas=True)
     if args.ssm_pallas:
         cfg = cfg.replace(ssm_pallas=True)
+    return cfg
+
+
+def main(argv: list[str] | None = None) -> None:
+    args = parse_args(argv)
+    logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
+    enable_compile_cache()
+    cfg = serve_config(args)
     mesh = parse_mesh(args.mesh)
     if mesh is not None:
         from repro.models.sharding import use_rules
@@ -159,18 +174,37 @@ def main() -> None:
         _serve(args, cfg, mesh)
 
 
-def _serve(args, cfg, mesh) -> None:
+def setup_plan(args: argparse.Namespace, cfg, mesh):
+    """Program the CMU for this run (``setup_plan_cache``): load the plan
+    at ``--plan-cache``, or autotune one for the run's prefill geometry and
+    decode buckets and save it there."""
     buckets = None if args.fixed_batch else serve_buckets(args.slots)
     quant = tuple(q for q in args.quant.split(",") if q) or None
-    setup_plan_cache(args.plan_cache, cfg, args.requests * args.prompt_len,
-                     mesh=mesh, decode_buckets=buckets, quant=quant,
-                     quant_budget=args.quant_budget)
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    return setup_plan_cache(args.plan_cache, cfg,
+                            args.requests * args.prompt_len, mesh=mesh,
+                            decode_buckets=buckets, quant=quant,
+                            quant_budget=args.quant_budget)
+
+
+def init_params(model: Model, mesh=None, seed: int = 0):
+    """Random weights from ``seed``, made on the device by one jitted
+    program: each weight is drawn and cast to ``param_dtype`` there, so no
+    f32 copy of a bf16 model ever exists, and under ``mesh`` (inside its
+    ``use_rules`` context) each leaf is written straight into its
+    sharding rather than placed on one device first."""
+    key = jax.random.PRNGKey(seed)
+    shardings = None
     if mesh is not None:
         from repro.models.sharding import param_shardings
 
-        params = jax.device_put(params, param_shardings(params))
+        shardings = param_shardings(jax.eval_shape(model.init, key))
+    return jax.jit(model.init, out_shardings=shardings)(key)
+
+
+def _serve(args, cfg, mesh) -> None:
+    setup_plan(args, cfg, mesh)
+    model = Model(cfg)
+    params = init_params(model, mesh, args.seed)
 
     if args.fixed_batch:
         _serve_fixed(args, cfg, model, params)
@@ -179,6 +213,14 @@ def _serve(args, cfg, mesh) -> None:
     trace = poisson_trace(
         args.requests, vocab=cfg.vocab_size, max_prompt=args.prompt_len,
         max_gen=args.gen, rate=args.arrival_rate, seed=args.seed)
+    serve_trace(args, model, params, trace)
+
+
+def serve_trace(args, model: Model, params, trace):
+    """Serve ``trace`` through the continuous-batching scheduler and print
+    what it did; with ``--verify``, raise ``SystemExit`` unless every
+    completed stream equals classic per-request decode.  Returns the
+    scheduler's ``(results, stats)``."""
     faults = (FaultPlan.from_spec(args.faults, seed=args.seed)
               if args.faults else None)
     sched = ServeScheduler(
@@ -190,7 +232,7 @@ def _serve(args, cfg, mesh) -> None:
     results, stats = sched.run(trace)
     wall = time.perf_counter() - t0
 
-    print(f"continuous batching: {args.requests} reqs, {stats.tokens} tokens "
+    print(f"continuous batching: {len(trace)} reqs, {stats.tokens} tokens "
           f"in {wall*1e3:.0f} ms ({stats.tokens/max(wall, 1e-9):,.0f} tok/s)")
     print(f"  {stats.steps} decode steps, {stats.prefills} prefills, "
           f"slot utilization {stats.slot_utilization:.2f}, "
@@ -231,6 +273,7 @@ def _serve(args, cfg, mesh) -> None:
         else:
             print(f"verify: {len(completed)}/{len(trace)} token streams "
                   "identical to per-request sequential decode")
+    return results, stats
 
 
 def _serve_fixed(args, cfg, model, params) -> None:

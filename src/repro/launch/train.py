@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data import DataConfig, TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import (
     init_train_state,
     make_train_step,
@@ -60,6 +61,7 @@ def main() -> None:
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.d_model:
         cfg = cfg.replace(d_model=args.d_model)

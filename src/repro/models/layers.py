@@ -508,9 +508,7 @@ def attention_full(
             idx = jax.lax.axis_index(seq_axes)
             return _attention_core(cfg, q_l, k_l, v_l, q_offset=idx * Sloc, **core)
 
-        from repro.launch.mesh import shard_map
-
-        o = shard_map(
+        o = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(q_spec, kv_spec, kv_spec),
             out_specs=q_spec,
@@ -602,9 +600,7 @@ def attention_decode(
             kpos = idx * Sloc + jnp.arange(Sloc)
             return _decode_core(q_l, k_l, v_l, kpos, pos_l, window, scale, seq_ax)
 
-        from repro.launch.mesh import shard_map
-
-        o = shard_map(
+        o = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(dp, None, None, None), P(dp, seq_ax, None, None),
                       P(dp, seq_ax, None, None), P()),

@@ -161,8 +161,10 @@ class Model:
             params["lm_head"] = Lyr._init(ks[2], (cfg.d_model, cfg.padded_vocab), scale=0.02)
 
         if cfg.family in ("dense", "moe", "vlm"):
-            blocks = [init_block(cfg, k) for k in Lyr.split_keys(ks[3], cfg.num_layers)]
-            params["layers"] = _stack(blocks)
+            # one vmapped draw per leaf (the same values as stacking
+            # per-layer draws) keeps the traced init independent of depth
+            params["layers"] = jax.vmap(lambda k: init_block(cfg, k))(
+                jax.random.split(ks[3], cfg.num_layers))
             if cfg.family == "vlm":
                 vin = cfg.vision_embed_dim or cfg.d_model
                 params["vision_proj"] = Lyr._init(ks[4], (vin, cfg.d_model))

@@ -32,6 +32,7 @@ def test_sharded_train_step_matches_single_device():
     """One train step on a 2x2 mesh == the same step on 1 device."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.models import Model, get_config
         from repro.models.sharding import use_rules, param_shardings
         from repro.launch.steps import init_train_state, make_train_step
@@ -46,7 +47,7 @@ def test_sharded_train_step_matches_single_device():
         step = make_train_step(m)
         p_ref, _, met_ref = jax.jit(step)(params, opt, batch)
 
-        mesh = jax.make_mesh((2, 2), ('data', 'model'))
+        mesh = make_mesh((2, 2), ('data', 'model'))
         with use_rules(mesh):
             p_sh = param_shardings(params)
             params_s = jax.device_put(params, p_sh)
@@ -65,6 +66,7 @@ def test_context_parallel_attention_matches_local():
     """shard_map seq-sharded attention == single-device attention."""
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.models import layers as L
         from repro.models.config import ModelConfig
         from repro.models.sharding import use_rules
@@ -74,7 +76,7 @@ def test_context_parallel_attention_matches_local():
         p = L.init_attention(cfg, jax.random.PRNGKey(0))
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 64, 64), jnp.float32)
         ref = L.attention_full(cfg, p, x, window=0)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         with use_rules(mesh):
             out = jax.jit(lambda x: L.attention_full(cfg, p, x, window=0))(x)
         d = float(jnp.abs(ref - out).max())
@@ -93,6 +95,7 @@ def test_context_parallel_attention_matches_local():
 def test_moe_block_local_dispatch_sharded_matches():
     out = run_py("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.models import layers as L
         from repro.models.config import ModelConfig
         from repro.models.sharding import use_rules
@@ -102,7 +105,7 @@ def test_moe_block_local_dispatch_sharded_matches():
         p = L.init_moe(cfg, jax.random.PRNGKey(0))
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 32), jnp.float32)
         ref, aux_ref = L.moe(cfg, p, x)   # NB=1 path
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         with use_rules(mesh):
             out, aux = jax.jit(lambda x: L.moe(cfg, p, x))(x)
         # block-local capacity differs from global capacity only via drops;
@@ -118,17 +121,17 @@ def test_compressed_psum_multidevice():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.launch.mesh import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.runtime import compressed_psum
 
-        mesh = jax.make_mesh((4,), ('x',))
+        mesh = make_mesh((4,), ('x',))
         gs = jax.random.normal(jax.random.PRNGKey(0), (4, 256), jnp.float32)
 
         def f(g):
             out, _ = compressed_psum(g[0], 'x')
             return out[None]
 
-        out = jax.jit(shard_map(f, mesh=mesh, in_specs=(P('x'),), out_specs=P('x')))(gs)
+        out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P('x'),), out_specs=P('x')))(gs)
         want = jnp.mean(gs, axis=0)
         err = float(jnp.abs(out[0] - want).max()) / (float(jnp.abs(want).max()) + 1e-9)
         assert err < 0.05, err
@@ -141,6 +144,7 @@ def test_elastic_reshard_2x2_to_4x1():
     """Checkpoint on one mesh, restore on another; train continues."""
     out = run_py("""
         import tempfile, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.models import Model, get_config
         from repro.models.sharding import use_rules, param_shardings
         from repro.launch.steps import init_train_state, make_train_step
@@ -150,12 +154,12 @@ def test_elastic_reshard_2x2_to_4x1():
         cfg = get_config('minicpm_2b', smoke=True)
         m = Model(cfg)
         params, opt = init_train_state(m, jax.random.PRNGKey(0))
-        mesh1 = jax.make_mesh((2, 2), ('data', 'model'))
+        mesh1 = make_mesh((2, 2), ('data', 'model'))
         with use_rules(mesh1):
             p1 = jax.device_put(params, param_shardings(params))
         with tempfile.TemporaryDirectory() as d:
             save_checkpoint(d, 1, p1)
-            mesh2 = jax.make_mesh((4, 1), ('data', 'model'))
+            mesh2 = make_mesh((4, 1), ('data', 'model'))
             with use_rules(mesh2):
                 sh2 = param_shardings(params)
                 p2, _ = load_checkpoint(d, 1, params, shardings=sh2)
@@ -174,10 +178,11 @@ def test_dryrun_single_cell_small_mesh():
     """The dry-run path end-to-end on an 8-device 4x2 production-mesh stand-in."""
     out = run_py("""
         import jax
+        from repro.launch.mesh import make_mesh
         import repro.launch.mesh as mesh_mod
         mesh_mod.make_production_mesh = lambda multi_pod=False: (
-            jax.make_mesh((2, 2, 2), ('pod', 'data', 'model')) if multi_pod
-            else jax.make_mesh((4, 2), ('data', 'model')))
+            make_mesh((2, 2, 2), ('pod', 'data', 'model')) if multi_pod
+            else make_mesh((4, 2), ('data', 'model')))
         import repro.launch.dryrun as dr
         dr.make_production_mesh = mesh_mod.make_production_mesh
         import repro.launch.specs as specs

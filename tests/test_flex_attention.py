@@ -25,6 +25,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 import numpy as np
 import pytest
 from _propcheck import given, settings, st
@@ -139,9 +140,9 @@ def _all_avals(jaxpr, acc):
 
 
 def _iter_jaxprs(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, Jaxpr):
         yield val
     elif isinstance(val, (list, tuple)):
         for item in val:

@@ -25,6 +25,7 @@ import tempfile
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr
 import numpy as np
 import pytest
 from _propcheck import given, settings, st
@@ -183,7 +184,7 @@ def test_strip_grid_axes_are_megacore_parallel():
                     return eqn
                 for v in eqn.params.values():
                     for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                        if isinstance(sub, jax.core.ClosedJaxpr):
+                        if isinstance(sub, ClosedJaxpr):
                             got = find(sub.jaxpr)
                             if got is not None:
                                 return got
@@ -191,7 +192,7 @@ def test_strip_grid_axes_are_megacore_parallel():
 
         eqn = find(jx.jaxpr)
         assert eqn is not None
-        return eqn.params["compiler_params"]["mosaic"]["dimension_semantics"]
+        return eqn.params["compiler_params"]["mosaic_tpu"].dimension_semantics
 
     blk = dict(block=(64, 64, 64), interpret=True)
     assert semantics(lambda a, b: fk.matmul_ws(a, b, strip=2, **blk)) == (
@@ -278,10 +279,20 @@ def test_budget_property_every_candidate_fits_vmem():
         for t, df, (bm, bk, bn), strip, _qd in ranked:
             cost = hbm_traffic_bytes(g, df, bm, bk, bn, strip=strip)
             assert cost.vmem_bytes <= VMEM_BUDGET_BYTES
-            # strips charge the f32 accumulator strip PLUS the fused
-            # kernels' same-extent copy-out buffer (4 + out_bytes per elem)
-            acc = strip * bm * bn * 8 if strip > 1 else bm * bn * 4
-            recomputed = (bm * bk + bk * bn) * 2 + acc
+            # what Mosaic allocates: double-buffered operand, bias-row and
+            # residual blocks, double-buffered output + f32 staging blocks
+            # over the strip, OS's f32 scratch, three f32 temporaries
+            rows = strip * bm if df is Dataflow.WS else bm
+            cols = strip * bn if df is Dataflow.IS else bn
+            tile = lambda r, c, b: (-(-r // (32 // b)) * (32 // b)
+                                    * -(-c // 128) * 128 * b)
+            ins = (max(tile(bm, bk, 2), tile(bk, bm, 2))
+                   + max(tile(bk, bn, 2), tile(bn, bk, 2))
+                   + tile(1, cols, 4) + tile(rows, cols, 4))
+            outs = 2 * tile(rows, cols, 4)
+            scratch = tile(bm, bn, 4) if df is Dataflow.OS else 0
+            recomputed = (2 * ins + 2 * outs + scratch
+                          + 3 * tile(bm, bn, 4))
             assert cost.vmem_bytes == recomputed
             if df is Dataflow.OS:
                 assert strip == 1

@@ -23,6 +23,7 @@ import tempfile
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 import numpy as np
 import pytest
 from _propcheck import given, settings, st
@@ -133,9 +134,9 @@ def _all_primitives(jaxpr, out=None):
         out.add(eqn.primitive.name)
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, ClosedJaxpr):
                     _all_primitives(sub.jaxpr, out)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, Jaxpr):
                     _all_primitives(sub, out)
     return out
 

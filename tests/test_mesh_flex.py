@@ -193,7 +193,9 @@ def test_dp_size_single_definition():
 
 
 def _mesh24():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh((2, 4), ("data", "model"))
 
 
 def _linear_case(M=64, K=32, N=48, bias=True, residual=True):

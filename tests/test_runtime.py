@@ -217,10 +217,11 @@ def test_compressed_psum_single_device():
     """Semantics on an axis of size 1 (multi-device exercised in
     test_distributed.py subprocesses)."""
 
-    mesh = jax.make_mesh((1,), ("x",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("x",))
     g = jnp.asarray(np.random.default_rng(2).normal(size=(64,)), jnp.float32)
 
-    from repro.launch.mesh import shard_map
     from repro.runtime import compressed_psum
 
     def f(g):
@@ -228,7 +229,7 @@ def test_compressed_psum_single_device():
         return out, res
 
     out, res = jax.jit(
-        shard_map(f, mesh=mesh, in_specs=(jax.sharding.PartitionSpec("x"),),
+        jax.shard_map(f, mesh=mesh, in_specs=(jax.sharding.PartitionSpec("x"),),
                   out_specs=(jax.sharding.PartitionSpec("x"),) * 2)
     )(g)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g), atol=0.02)

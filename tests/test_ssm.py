@@ -2,6 +2,7 @@
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 import numpy as np
 import pytest
 from _propcheck import given, settings, st
@@ -205,9 +206,9 @@ def _count_scan_cumsums(jaxpr):
         for val in eqn.params.values():
             vals = val if isinstance(val, (list, tuple)) else [val]
             for sub in vals:
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, ClosedJaxpr):
                     n += _count_scan_cumsums(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, Jaxpr):
                     n += _count_scan_cumsums(sub)
     return n
 
