@@ -53,15 +53,27 @@ def parse_mesh(spec: str):
     return make_mesh((d, m), ("data", "model"))
 
 
-def sequential_reference(model, params, requests, cache_len: int):
-    """Classic per-request serving: exact-length prefill, batch-1 decode.
-    The correctness oracle for the continuous-batching path."""
+def sequential_reference(model, params, requests, cache_len: int,
+                         width: int = 1):
+    """Classic per-request serving: exact-length prefill, then decode of
+    that one request over a dense cache.  The correctness oracle for the
+    continuous-batching path.
+
+    ``width`` runs the decode step at that batch width, the request in
+    every row.  XLA on a TPU compiles a step differently for each batch
+    width, and the rows of a batch-4 step need not equal a batch-1 step
+    bitwise (observed on a v5e at qwen3_4b's widths; every op alone is
+    batch-invariant there, the fused step is not).  Comparing a scheduler
+    that decoded at one bucket width against the reference at that width
+    keeps the check bitwise."""
     prefill = jax.jit(make_prefill_step(model, cache_len))
     decode = jax.jit(make_decode_step(model))
+    widen = lambda a: jnp.repeat(a, width, axis=1) if a.ndim > 1 else a
     out = {}
     for r in requests:
         cache, last = prefill(params, {"tokens": jnp.asarray(r.prompt[None])})
-        tok = jnp.argmax(last, -1).astype(jnp.int32)
+        cache = jax.tree.map(widen, cache)
+        tok = jnp.repeat(jnp.argmax(last, -1).astype(jnp.int32), width)
         toks = [tok]
         for _ in range(r.max_new - 1):
             logits, cache = decode(params, cache, tok)
@@ -251,7 +263,11 @@ def serve_trace(args, model: Model, params, trace):
     if args.verify:
         cache_len = sched.max_blocks * sched.block_size
         completed = [r for r in trace if results[r.rid].status.completed]
-        ref = sequential_reference(model, params, completed, cache_len)
+        # one decode width throughout: compare at that width (see
+        # ``sequential_reference``); several: the batch-1 reference
+        widths = set(stats.bucket_per_step)
+        width = widths.pop() if len(widths) == 1 else 1
+        ref = sequential_reference(model, params, completed, cache_len, width)
         bad = [r.rid for r in completed
                if not np.array_equal(results[r.rid].tokens, ref[r.rid])]
         if bad:

@@ -322,9 +322,21 @@ def _attention_core(
     Skv = k.shape[1]
     Hkv = k.shape[2]
     g = H // Hkv
-    cq = cfg.attn_chunk if (Sq % cfg.attn_chunk == 0 and Sq > cfg.attn_chunk) else Sq
-    ckv = cfg.attn_chunk if Skv % cfg.attn_chunk == 0 and Skv > cfg.attn_chunk else Skv
-    nq, nkv = Sq // cq, Skv // ckv
+    # KV tiles of attn_chunk whatever the length: a ragged sequence is
+    # padded up to whole tiles (pad keys masked, pad query rows dropped), so
+    # a position's output never depends on how far its sequence was padded
+    # — the online softmax sums in tile order, and a prompt served at its
+    # exact length must equal the same prompt padded to a length bucket.
+    cq = min(cfg.attn_chunk, Sq)
+    ckv = cfg.attn_chunk
+    Sq_pad, Skv_pad = -(-Sq // cq) * cq, -(-Skv // ckv) * ckv
+    if Sq_pad != Sq:
+        q = jnp.pad(q, ((0, 0), (0, Sq_pad - Sq), (0, 0), (0, 0)))
+    if Skv_pad != Skv:
+        k = jnp.pad(k, ((0, 0), (0, Skv_pad - Skv), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, Skv_pad - Skv), (0, 0), (0, 0)))
+    nq, nkv = Sq_pad // cq, Skv_pad // ckv
+    Sq, Sq_out, Skv_real, Skv = Sq_pad, Sq, Skv, Skv_pad
     kv_slice = min(Skv, window + cq) if (window and causal) else Skv
 
     def kv_tile(carry, q_c, qpos, kv0):
@@ -336,7 +348,7 @@ def _attention_core(
         qg = q_c.reshape(B, cq, Hkv, g, hd)
         s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32), k_c.astype(jnp.float32))
         s = s * scale
-        m = jnp.ones((cq, ckv), bool)
+        m = jnp.broadcast_to(kpos[None, :] < Skv_real, (cq, ckv))
         if causal:
             m = m & (kpos[None, :] <= qpos[:, None])
             if window:
@@ -375,7 +387,9 @@ def _attention_core(
                 kpos = k0 + jnp.arange(ct)
                 qg = q_c.reshape(B, cq, Hkv, g, hd)
                 s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32), kc.astype(jnp.float32)) * scale
-                m = (kpos[None, :] <= qpos[:, None]) & (qpos[:, None] - kpos[None, :] < window)
+                m = ((kpos[None, :] <= qpos[:, None])
+                     & (qpos[:, None] - kpos[None, :] < window)
+                     & (kpos[None, :] < Skv_real))
                 if prefix_len:
                     m = m | (kpos[None, :] < prefix_len)
                 s = jnp.where(m[None, None, None], s, -1e30)
@@ -414,11 +428,11 @@ def _attention_core(
 
     q_chunk_ck = jax.checkpoint(q_chunk, static_argnums=())
     if nq == 1:
-        return q_chunk(0)
+        return q_chunk(0)[:, :Sq_out]
     if cfg.attn_unroll:
-        return jnp.concatenate([q_chunk(c) for c in range(nq)], axis=1)
+        return jnp.concatenate([q_chunk(c) for c in range(nq)], axis=1)[:, :Sq_out]
     _, os = jax.lax.scan(lambda _, c: (None, q_chunk_ck(c)), None, jnp.arange(nq))
-    return jnp.moveaxis(os, 0, 1).reshape(B, Sq, H, hd)
+    return jnp.moveaxis(os, 0, 1).reshape(B, Sq, H, hd)[:, :Sq_out]
 
 
 def _attn_schedule() -> tuple[str, tuple[int, int]]:
