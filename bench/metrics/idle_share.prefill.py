@@ -1,0 +1,8 @@
+"""1 - (union of the device's op intervals in the window) / window, in %
+(device trace)."""
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)
