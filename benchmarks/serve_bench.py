@@ -2,8 +2,8 @@
 
 Replays a synthetic Poisson trace (mixed prompt/generation lengths) through
 the ``launch.scheduler`` continuous-batching runtime and through the legacy
-fixed-batch loop, and reports tokens/s, p50/p99 per-token latency, slot
-utilization, and the decode bucket histogram.  Both paths get one untimed
+fixed-batch loop, and reports tokens/s, slot utilization, and the decode
+bucket histogram.  Both paths get one untimed
 warm-up replay first so compile time never pollutes the comparison.
 
 The default ``--profile bench`` model (d=512, 4 layers) is deliberately
@@ -49,28 +49,6 @@ def build_model(profile: str):
     return cfg, model, params
 
 
-def latency_percentiles(events: list[tuple[int, int, float]]) -> dict:
-    """Per-token latency percentiles from the scheduler's sync-event stream.
-
-    Events are ``(decode steps so far, tokens so far, perf_counter)`` at
-    every admission/eviction sync.  For consecutive events with a token
-    delta, the segment walltime is attributed evenly across its tokens —
-    the finest-grained latency the no-per-step-sync discipline can observe
-    without reintroducing the per-step host sync it exists to avoid.
-    """
-    per_token: list[float] = []
-    for (s0, k0, t0), (s1, k1, t1) in zip(events, events[1:]):
-        dk = k1 - k0
-        if dk > 0:
-            per_token.extend([(t1 - t0) / dk] * dk)
-    if not per_token:
-        return {"p50": 0.0, "p99": 0.0, "mean": 0.0}
-    arr = np.asarray(per_token)
-    return {"p50": float(np.percentile(arr, 50)),
-            "p99": float(np.percentile(arr, 99)),
-            "mean": float(arr.mean())}
-
-
 def run_continuous(model, params, trace, args):
     from repro.launch.scheduler import ServeScheduler
 
@@ -93,7 +71,6 @@ def run_continuous(model, params, trace, args):
         "prefills": stats.prefills,
         "slot_utilization": stats.slot_utilization,
         "bucket_histogram": {str(k): v for k, v in stats.bucket_histogram().items()},
-        "latency_per_token_s": latency_percentiles(stats.events),
     }
 
 
@@ -453,13 +430,10 @@ def main() -> None:
           f"arrival rate {args.rate}/step")
 
     clean_results, cont = run_continuous(model, params, trace, args)
-    lat = cont["latency_per_token_s"]
     print(f"continuous: {cont['tokens']} tok in {cont['walltime_s']*1e3:.0f} ms "
           f"= {cont['tokens_per_s']:,.0f} tok/s | {cont['decode_steps']} steps, "
           f"util {cont['slot_utilization']:.2f}, "
           f"buckets {cont['bucket_histogram']}")
-    print(f"  per-token latency p50 {lat['p50']*1e3:.2f} ms, "
-          f"p99 {lat['p99']*1e3:.2f} ms")
 
     _, fixed = run_fixed(model, params, trace)
     print(f"fixed batch: {fixed['tokens']} tok in {fixed['walltime_s']*1e3:.0f} ms "

@@ -131,9 +131,7 @@ SERVE_SCHEMA = Schema({
                          "head_dim": int, "vocab_size": int}},
     "continuous": {**_LANE, "prefills": positive,
                    "slot_utilization": fraction,
-                   "bucket_histogram": dict,
-                   "latency_per_token_s": {"p50": positive, "p99": positive,
-                                           "mean": positive}},
+                   "bucket_histogram": dict},
     "fixed_batch": {**_LANE, "row_steps": positive},
     "speedup_tokens_per_s": positive,
     "faulted": {"spec": str, "walltime_s": positive, "requests": positive,

@@ -326,12 +326,14 @@ def _linear_run(cfg: _LinearCfg, x, w, b, residual, save_preact: bool):
     wp = _pad_to(w, bk, bn)
     bp = None if b is None else _pad_to(b.reshape(1, N), 1, bn)
     rp = None if residual is None else _pad_to(residual, bm, bn)
-    out = fk.fused_matmul(
-        xp, wp, dataflow,
-        bias=bp, residual=rp, activation=cfg.activation, out_dtype=odt,
-        block=(bm, bk, bn), interpret=cfg.interpret, save_preact=save_preact,
-        strip=strip, qscale=qscale,
-    )
+    # the dispatched dataflow names the kernel's scope ("is"/"os"/"ws")
+    with jax.named_scope(dataflow.name.lower()):
+        out = fk.fused_matmul(
+            xp, wp, dataflow,
+            bias=bp, residual=rp, activation=cfg.activation, out_dtype=odt,
+            block=(bm, bk, bn), interpret=cfg.interpret, save_preact=save_preact,
+            strip=strip, qscale=qscale,
+        )
     if save_preact:
         out, z = out
         return out[:M, :N].astype(odt), z[:M, :N]

@@ -58,17 +58,29 @@ Host/device sync discipline: tokens live in a device-resident slot array
 and are folded back with lazy ``.at[].set``; the loop never calls
 ``np.asarray`` per step (the old loop's per-step host sync).  The only
 blocking syncs are at admission/eviction/preemption events — where the
-host must inspect schedule state anyway — and each one timestamps the
-event stream that ``benchmarks/serve_bench.py`` turns into per-token
-latencies.  The non-finite-logit guard rides the same discipline: decode
-emits a per-row finiteness flag that accumulates device-side next to the
-tokens and is inspected only at the end-of-run drain (injected poison is
-additionally evicted eagerly, since the host scheduled it and needs no
-readback to know).
+host must inspect schedule state anyway — and each one timestamps
+``ServeStats.events``.  The non-finite-logit guard rides the same
+discipline: decode emits a per-row finiteness flag that accumulates
+device-side next to the tokens and is inspected only at the end-of-run
+drain (injected poison is additionally evicted eagerly, since the host
+scheduled it and needs no readback to know).
+
+Host spans: ``ServeStats.spans`` records what the host did, as
+``(name, t0, t1, step, rid)`` on ``time.perf_counter``, each span also
+opened as a ``jax.profiler.TraceAnnotation`` so that a profiler trace
+shows it on the device's clock.  ``serve.run`` is the root; inside it
+``serve.admit`` (one prefill: padded prompt, table, uploads, dispatch),
+``serve.decode`` (one step: poison mask, table and position uploads,
+dispatch), ``serve.sync`` (``block_until_ready`` at an event) and
+``serve.drain`` (the one transfer after the loop).  Spans nest on one
+thread; ``serve.run``'s time outside its children is the scheduler's own
+bookkeeping.  ``step`` is the decode steps done when the span opened and
+``rid`` the request, -1 where none applies.
 """
 
 from __future__ import annotations
 
+import contextlib
 import enum
 import logging
 import time
@@ -151,6 +163,29 @@ class ServeStats:
     bucket_per_step: list[int] = field(default_factory=list)
     # (decode steps so far, tokens so far, perf_counter) at every sync event
     events: list[tuple[int, int, float]] = field(default_factory=list)
+    # (name, perf_counter at start, at end, step, rid) per host span
+    spans: list[tuple[str, float, float, int, int]] = field(default_factory=list)
+
+    @contextlib.contextmanager
+    def span(self, name: str, *, step: int = -1, rid: int = -1):
+        """Record the host time of the block as a span, inside a profiler
+        annotation of the same name (inactive unless a trace runs)."""
+        with jax.profiler.TraceAnnotation(name, step=step, rid=rid):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.spans.append((name, t0, time.perf_counter(), step, rid))
+
+    def host_seconds(self) -> dict[str, float]:
+        """Seconds per span name, and ``bookkeeping``: ``serve.run``'s time
+        outside its child spans."""
+        out: dict[str, float] = {}
+        for name, t0, t1, _, _ in self.spans:
+            out[name] = out.get(name, 0.0) + (t1 - t0)
+        root = out.pop("serve.run", 0.0)
+        out["bookkeeping"] = root - sum(out.values())
+        return out
 
     @property
     def slot_utilization(self) -> float:
@@ -313,8 +348,13 @@ class ServeScheduler:
     # -- the loop ----------------------------------------------------------
 
     def run(self, requests: list[Request]) -> tuple[dict[int, RequestResult], ServeStats]:
-        results: dict[int, RequestResult] = {}
         stats = ServeStats(capacity=self.capacity)
+        with stats.span("serve.run"):
+            results = self._run(requests, stats)
+        return results, stats
+
+    def _run(self, requests: list[Request], stats: ServeStats) -> dict[int, RequestResult]:
+        results: dict[int, RequestResult] = {}
         faults = self.faults
         if faults is not None:
             faults.reset()
@@ -356,7 +396,8 @@ class ServeScheduler:
         emitted: list[tuple[jax.Array, jax.Array, tuple[int, ...]]] = []
 
         def note_event():
-            jax.block_until_ready(tok)
+            with stats.span("serve.sync", step=stats.steps):
+                jax.block_until_ready(tok)
             stats.events.append((stats.steps, tokens_out, time.perf_counter()))
 
         def remove_slot(i: int, status: RequestStatus | None):
@@ -439,9 +480,10 @@ class ServeScheduler:
                 first_admit.setdefault(r.rid, step)
                 if preempts.get(r.rid):
                     stats.replays += 1
-                tok, pool_k, pool_v, first, ok = self._admit(
-                    r, len(slots), blocks, slots, tables, positions, tok,
-                    pool_k, pool_v, step)
+                with stats.span("serve.admit", step=stats.steps, rid=r.rid):
+                    tok, pool_k, pool_v, first, ok = self._admit(
+                        r, len(slots), blocks, slots, tables, positions, tok,
+                        pool_k, pool_v, step)
                 emitted.append((first, ok, (r.rid,)))
                 tokens_out += 1
                 stats.prefills += 1
@@ -483,24 +525,25 @@ class ServeScheduler:
                         preemptions=preempts.get(r.rid, 0))
                     continue
                 break
-            b = self.bucket(len(slots))
-            poison = np.zeros((b,), bool)
-            poisoned = None
-            if faults is not None:
-                dt = faults.spike()
-                if dt:
-                    time.sleep(dt)
-                poisoned = faults.pick_poison(step, len(slots))
-                if poisoned is not None:
-                    poison[poisoned] = True
-            # host copies: the step runs asynchronously, and on the CPU
-            # backend jnp.asarray aliases the numpy buffer, which the
-            # bookkeeping below mutates before the step may have read it
-            tok_b, ok_b, pool_k, pool_v = self._decode(
-                self.params, pool_k, pool_v,
-                jnp.asarray(tables[:b].copy()), jnp.asarray(positions[:b].copy()),
-                tok[:b], jnp.asarray(poison))
-            tok = tok.at[:b].set(tok_b)
+            with stats.span("serve.decode", step=stats.steps):
+                b = self.bucket(len(slots))
+                poison = np.zeros((b,), bool)
+                poisoned = None
+                if faults is not None:
+                    dt = faults.spike()
+                    if dt:
+                        time.sleep(dt)
+                    poisoned = faults.pick_poison(step, len(slots))
+                    if poisoned is not None:
+                        poison[poisoned] = True
+                # host copies: the step runs asynchronously, and on the CPU
+                # backend jnp.asarray aliases the numpy buffer, which the
+                # bookkeeping below mutates before the step may have read it
+                tok_b, ok_b, pool_k, pool_v = self._decode(
+                    self.params, pool_k, pool_v,
+                    jnp.asarray(tables[:b].copy()), jnp.asarray(positions[:b].copy()),
+                    tok[:b], jnp.asarray(poison))
+                tok = tok.at[:b].set(tok_b)
             step += 1
             stats.steps += 1
             stats.active_per_step.append(len(slots))
@@ -531,7 +574,8 @@ class ServeScheduler:
         note_event()
         self.kv.k, self.kv.v = pool_k, pool_v
         stats.tokens = tokens_out
-        self._drain(emitted, results)
+        with stats.span("serve.drain"):
+            self._drain(emitted, results)
         stats.failures = sum(
             1 for res in results.values()
             if res.status is RequestStatus.FAILED)
@@ -539,7 +583,7 @@ class ServeScheduler:
             stats.faults_injected = dict(faults.injected)
         missing = {r.rid for r in requests} - set(results)
         assert not missing, f"requests {missing} ended without a status"
-        return results, stats
+        return results
 
     def _admit(self, r: Request, row: int, blocks: list[int], slots, tables,
                positions, tok, pool_k, pool_v, step: int):

@@ -249,6 +249,12 @@ def serve_trace(args, model: Model, params, trace):
     print(f"  {stats.steps} decode steps, {stats.prefills} prefills, "
           f"slot utilization {stats.slot_utilization:.2f}, "
           f"bucket histogram {stats.bucket_histogram()}")
+    host = stats.host_seconds()
+    print("  host ms: " + ", ".join(
+        f"{label} {host.get(name, 0.0) * 1e3:.1f}" for label, name in (
+            ("admit", "serve.admit"), ("decode dispatch", "serve.decode"),
+            ("sync wait", "serve.sync"), ("drain", "serve.drain"),
+            ("bookkeeping", "bookkeeping"))))
     if faults is not None or stats.rejections or stats.timeouts:
         statuses: dict[str, int] = {}
         for res in results.values():

@@ -11,6 +11,7 @@ path, where XLA must see a fusible dot for cost_analysis).
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any
 
@@ -59,7 +60,18 @@ def linear(
     sub-plan (or the trace-time analytical argmin).  Layers that don't
     divide the mesh fall back cleanly to the single-device kernel path —
     the same contract as the attention shard_map path.
+
+    The layer runs under ``jax.named_scope(name)``; inside it the kernel
+    runs under its dispatched dataflow's scope (``is``/``os``/``ws``, set
+    by ``kernels.ops``) and the XLA path under ``xla``, so that a device
+    trace gives the time of each projection and of each path.
     """
+    with jax.named_scope(name) if name else contextlib.nullcontext():
+        return _linear(cfg, x, w, b, activation=activation,
+                       residual=residual, name=name)
+
+
+def _linear(cfg, x, w, b, *, activation, residual, name):
     w = w.astype(x.dtype)
     if cfg.use_pallas:
         from repro.core.dataflow import GemmShape, best_kernel_dataflow
@@ -133,13 +145,14 @@ def linear(
             bwd_dx=bwd_dx, bwd_dw=bwd_dw, strip=strip, qdtype=qdtype,
         )
         return out.reshape(*lead, N)
-    y = jnp.einsum("...d,df->...f", x, w)
-    if b is not None:
-        y = y + b.astype(y.dtype)
-    if activation is not None:
-        y = _XLA_ACT[activation](y)
-    if residual is not None:
-        y = y + residual
+    with jax.named_scope("xla"):
+        y = jnp.einsum("...d,df->...f", x, w)
+        if b is not None:
+            y = y + b.astype(y.dtype)
+        if activation is not None:
+            y = _XLA_ACT[activation](y)
+        if residual is not None:
+            y = y + residual
     return y
 
 
@@ -481,8 +494,6 @@ def attention_full(
     gemma3's local layers stay sub-quadratic in the HLO.  Falls back to the
     single-device path when no mesh is active or shapes don't divide.
     """
-    from repro.models.sharding import active_mesh, extent, spec_for
-
     B, S, D = x.shape
     q, k, v = _project_qkv(cfg, p, x, xkv)
     Skv = k.shape[1]
@@ -494,43 +505,51 @@ def attention_full(
     scale = 1.0 / math.sqrt(cfg.head_dim)
     core = dict(causal=causal, window=window, prefix_len=prefix_len, scale=scale)
 
+    with jax.named_scope("attn.core"):
+        o = _attention_full_core(cfg, q, k, v, core)
+    o = constrain(o, "act_batch", "act_seq", None, None)
+    return linear(cfg, o.reshape(B, S, cfg.q_dim), p["wo"],
+                  residual=residual, name="attn.wo")
+
+
+def _attention_full_core(cfg: ModelConfig, q, k, v, core: dict) -> jax.Array:
+    """The attention of ``attention_full`` after the projections: the flex
+    flash kernel, the jnp flash core, or that core under a context-parallel
+    shard_map."""
+    from repro.models.sharding import active_mesh, extent, spec_for
+
+    B, S = q.shape[:2]
     mesh = active_mesh()
     ext = extent("act_seq")
     if mesh is None or ext <= 1 or S % ext:
-        if (cfg.attn_pallas and causal and not window and not prefix_len
-                and Skv == S):
+        if (cfg.attn_pallas and core["causal"] and not core["window"]
+                and not core["prefix_len"] and k.shape[1] == S):
             # the planned flex flash kernel (self-attention prefill shapes;
             # windowed/prefix/cross layers keep the jnp core)
             from repro.kernels.flash_attention import mha_flash
             from repro.kernels.ops import default_interpret
 
             sweep, (bq, bk) = _attn_schedule()
-            o = mha_flash(q, k, v, causal=True, block_q=bq, block_k=bk,
-                          sweep=sweep, interpret=default_interpret())
-        else:
-            o = _attention_core(cfg, q, k, v, q_offset=0, **core)
-    else:
-        from jax.sharding import PartitionSpec as P
+            return mha_flash(q, k, v, causal=True, block_q=bq, block_k=bk,
+                             sweep=sweep, interpret=default_interpret())
+        return _attention_core(cfg, q, k, v, q_offset=0, **core)
+    from jax.sharding import PartitionSpec as P
 
-        seq_axes = spec_for("act_seq")[0]
-        dp = spec_for("act_batch")[0] if B % extent("act_batch") == 0 else None
-        q_spec = P(dp, seq_axes, None, None)
-        kv_spec = P(dp, None, None, None)
-        Sloc = S // ext
+    seq_axes = spec_for("act_seq")[0]
+    dp = spec_for("act_batch")[0] if B % extent("act_batch") == 0 else None
+    q_spec = P(dp, seq_axes, None, None)
+    kv_spec = P(dp, None, None, None)
+    Sloc = S // ext
 
-        def local_fn(q_l, k_l, v_l):
-            idx = jax.lax.axis_index(seq_axes)
-            return _attention_core(cfg, q_l, k_l, v_l, q_offset=idx * Sloc, **core)
+    def local_fn(q_l, k_l, v_l):
+        idx = jax.lax.axis_index(seq_axes)
+        return _attention_core(cfg, q_l, k_l, v_l, q_offset=idx * Sloc, **core)
 
-        o = jax.shard_map(
-            local_fn, mesh=mesh,
-            in_specs=(q_spec, kv_spec, kv_spec),
-            out_specs=q_spec,
-        )(q, k, v)
-
-    o = constrain(o, "act_batch", "act_seq", None, None)
-    return linear(cfg, o.reshape(B, S, cfg.q_dim), p["wo"],
-                  residual=residual, name="attn.wo")
+    return jax.shard_map(
+        local_fn, mesh=mesh,
+        in_specs=(q_spec, kv_spec, kv_spec),
+        out_specs=q_spec,
+    )(q, k, v)
 
 
 def _decode_core(q, k, v, kpos, pos, window: int, scale: float, axis: str | None):
@@ -601,7 +620,8 @@ def attention_decode(
     Hkv = cfg.num_kv_heads
     if mesh is None or ext <= 1 or Smax % ext or Hkv % ext == 0:
         # single-device, or the cache is head-sharded (divisible kv heads)
-        o = _decode_core(q, k, v, jnp.arange(Smax), pos, window, scale, None)
+        with jax.named_scope("attn.core"):
+            o = _decode_core(q, k, v, jnp.arange(Smax), pos, window, scale, None)
     else:
         from jax.sharding import PartitionSpec as P
 
@@ -614,12 +634,13 @@ def attention_decode(
             kpos = idx * Sloc + jnp.arange(Sloc)
             return _decode_core(q_l, k_l, v_l, kpos, pos_l, window, scale, seq_ax)
 
-        o = jax.shard_map(
-            local_fn, mesh=mesh,
-            in_specs=(P(dp, None, None, None), P(dp, seq_ax, None, None),
-                      P(dp, seq_ax, None, None), P()),
-            out_specs=P(dp, None, None, None),
-        )(q, k, v, pos)
+        with jax.named_scope("attn.core"):
+            o = jax.shard_map(
+                local_fn, mesh=mesh,
+                in_specs=(P(dp, None, None, None), P(dp, seq_ax, None, None),
+                          P(dp, seq_ax, None, None), P()),
+                out_specs=P(dp, None, None, None),
+            )(q, k, v, pos)
 
     out = linear(cfg, o.reshape(B, 1, cfg.q_dim), p["wo"], name="attn.wo")
     return out, {"k": k, "v": v}
@@ -661,10 +682,11 @@ def attention_decode_paged(
         k_new = rope(k_new, positions[:, None], cfg.rope_theta)
     bs = pk.shape[1]
     Hkv, hd = pk.shape[2], pk.shape[3]
-    blk = jnp.take_along_axis(table, (positions // bs)[:, None], axis=1)[:, 0]
-    off = positions % bs
-    pk = pk.at[blk, off].set(k_new[:, 0].astype(pk.dtype))
-    pv = pv.at[blk, off].set(v_new[:, 0].astype(pv.dtype))
+    with jax.named_scope("kv.append"):
+        blk = jnp.take_along_axis(table, (positions // bs)[:, None], axis=1)[:, 0]
+        off = positions % bs
+        pk = pk.at[blk, off].set(k_new[:, 0].astype(pk.dtype))
+        pv = pv.at[blk, off].set(v_new[:, 0].astype(pv.dtype))
     scale = 1.0 / math.sqrt(cfg.head_dim)
     if cfg.attn_pallas and _attn_decode_kind(B) == "paged":
         # in-place Pallas kernel: K/V blocks stream straight out of the
@@ -672,16 +694,19 @@ def attention_decode_paged(
         from repro.kernels.flash_attention import paged_attention
         from repro.kernels.ops import default_interpret
 
-        o = paged_attention(q[:, 0], pk, pv, table, positions, scale=scale,
-                            window=window,
-                            interpret=default_interpret())[:, None]
+        with jax.named_scope("attn.core"):
+            o = paged_attention(q[:, 0], pk, pv, table, positions, scale=scale,
+                                window=window,
+                                interpret=default_interpret())[:, None]
     else:
         # dense per-slot view: gathered entry j is the slot's logical
         # position j
-        k = pk[table].reshape(B, -1, Hkv, hd)
-        v = pv[table].reshape(B, -1, Hkv, hd)
-        o = _decode_core(q, k, v, jnp.arange(k.shape[1]), positions, window,
-                         scale, None)
+        with jax.named_scope("attn.kv_gather"):
+            k = pk[table].reshape(B, -1, Hkv, hd)
+            v = pv[table].reshape(B, -1, Hkv, hd)
+        with jax.named_scope("attn.core"):
+            o = _decode_core(q, k, v, jnp.arange(k.shape[1]), positions, window,
+                             scale, None)
     out = linear(cfg, o.reshape(B, 1, cfg.q_dim), p["wo"], name="attn.wo")
     return out, pk, pv
 

@@ -431,7 +431,8 @@ class Model:
             lp, gi = inp
             ks, vs = [], []
             for j in range(pat):
-                pj = jax.tree.map(lambda a, j=j: a[j], lp)
+                with jax.named_scope("weights"):  # this layer's weight slabs
+                    pj = jax.tree.map(lambda a, j=j: a[j], lp)
                 h = Lyr.norm(cfg, pj["ln1"], x)
                 q, k, v = Lyr._project_qkv(cfg, pj["attn"], h)
                 k = Lyr.rope(k, jnp.arange(Sp), cfg.rope_theta)
@@ -710,16 +711,19 @@ class Model:
             x, pool_k, pool_v = carry
             lp, g = inp
             for j in range(pat):
-                pj = jax.tree.map(lambda a, j=j: a[j], lp)
+                with jax.named_scope("weights"):  # this layer's weight slabs
+                    pj = jax.tree.map(lambda a, j=j: a[j], lp)
                 li = g * pat + j
-                kl = jax.lax.dynamic_index_in_dim(pool_k, li, 0, keepdims=False)
-                vl = jax.lax.dynamic_index_in_dim(pool_v, li, 0, keepdims=False)
+                with jax.named_scope("kv_pool.read"):
+                    kl = jax.lax.dynamic_index_in_dim(pool_k, li, 0, keepdims=False)
+                    vl = jax.lax.dynamic_index_in_dim(pool_v, li, 0, keepdims=False)
                 x, kl, vl = block_decode_paged(
                     cfg, pj, x, kl, vl, table, positions,
                     window=cfg.window_pattern[j],
                 )
-                pool_k = jax.lax.dynamic_update_index_in_dim(pool_k, kl, li, 0)
-                pool_v = jax.lax.dynamic_update_index_in_dim(pool_v, vl, li, 0)
+                with jax.named_scope("kv_pool.write"):
+                    pool_k = jax.lax.dynamic_update_index_in_dim(pool_k, kl, li, 0)
+                    pool_v = jax.lax.dynamic_update_index_in_dim(pool_v, vl, li, 0)
             return (x, pool_k, pool_v), None
 
         (x, nk, nv), _ = self._scan(

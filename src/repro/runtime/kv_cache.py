@@ -26,6 +26,7 @@ never rides a traced value, so the decode step keeps its fixed shape.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 # Reserved scratch block: pad rows of a bucketed batch write (and point
@@ -140,6 +141,7 @@ def write_prefill_blocks(pool_k, pool_v, k_all, v_all, table):
     L, B, S = k_all.shape[:3]
     bs = pool_k.shape[2]
     nb = S // bs
-    k_r = k_all.reshape(L, B, nb, bs, *k_all.shape[3:]).astype(pool_k.dtype)
-    v_r = v_all.reshape(L, B, nb, bs, *v_all.shape[3:]).astype(pool_v.dtype)
-    return pool_k.at[:, table].set(k_r), pool_v.at[:, table].set(v_r)
+    with jax.named_scope("kv.scatter"):
+        k_r = k_all.reshape(L, B, nb, bs, *k_all.shape[3:]).astype(pool_k.dtype)
+        v_r = v_all.reshape(L, B, nb, bs, *v_all.shape[3:]).astype(pool_v.dtype)
+        return pool_k.at[:, table].set(k_r), pool_v.at[:, table].set(v_r)
