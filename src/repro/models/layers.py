@@ -658,22 +658,25 @@ def attention_decode_paged(
     x: jax.Array,
     pk: jax.Array,
     pv: jax.Array,
+    base: jax.Array,
     table: jax.Array,
     positions: jax.Array,
     *,
     window: int = 0,
     use_rope: bool = True,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token decode against one layer's paged KV block pool.
+    """One-token decode against one layer's blocks of the stacked KV pools.
 
-    x (B,1,D); pk/pv (num_blocks, bs, Hkv, hd) — this layer's block pools;
+    x (B,1,D); pk/pv (L·num_blocks, bs, Hkv, hd) — every layer's block
+    pools, flattened so that this layer's block ``b`` is row ``base + b``;
     table (B, nb) int32 per-slot block tables; positions (B,) per-slot write
     positions (= tokens already cached for that slot).  The new K/V lands at
-    ``(table[pos // bs], pos % bs)`` per slot, then attention runs over the
-    gathered dense view of each slot's table with the per-slot causal mask
-    of ``_decode_core``.  Pad slots of a bucketed batch point their whole
-    table at the reserved scratch block, so their writes never touch a live
-    request's blocks and their garbage reads are masked to exact zeros.
+    ``(base + table[pos // bs], pos % bs)`` per slot, in place, then
+    attention runs over the gathered dense view of each slot's table with
+    the per-slot causal mask of ``_decode_core``.  Pad slots of a bucketed
+    batch point their whole table at the reserved scratch block, so their
+    writes land in this layer's scratch block, never in a live request's
+    blocks, and their garbage reads are masked to exact zeros.
     """
     B, _, D = x.shape
     q, k_new, v_new = _project_qkv(cfg, p, x)
@@ -682,8 +685,9 @@ def attention_decode_paged(
         k_new = rope(k_new, positions[:, None], cfg.rope_theta)
     bs = pk.shape[1]
     Hkv, hd = pk.shape[2], pk.shape[3]
+    rows = base + table  # this layer's blocks, as rows of the stacked pools
     with jax.named_scope("kv.append"):
-        blk = jnp.take_along_axis(table, (positions // bs)[:, None], axis=1)[:, 0]
+        blk = jnp.take_along_axis(rows, (positions // bs)[:, None], axis=1)[:, 0]
         off = positions % bs
         pk = pk.at[blk, off].set(k_new[:, 0].astype(pk.dtype))
         pv = pv.at[blk, off].set(v_new[:, 0].astype(pv.dtype))
@@ -695,15 +699,15 @@ def attention_decode_paged(
         from repro.kernels.ops import default_interpret
 
         with jax.named_scope("attn.core"):
-            o = paged_attention(q[:, 0], pk, pv, table, positions, scale=scale,
+            o = paged_attention(q[:, 0], pk, pv, rows, positions, scale=scale,
                                 window=window,
                                 interpret=default_interpret())[:, None]
     else:
         # dense per-slot view: gathered entry j is the slot's logical
         # position j
         with jax.named_scope("attn.kv_gather"):
-            k = pk[table].reshape(B, -1, Hkv, hd)
-            v = pv[table].reshape(B, -1, Hkv, hd)
+            k = pk[rows].reshape(B, -1, Hkv, hd)
+            v = pv[rows].reshape(B, -1, Hkv, hd)
         with jax.named_scope("attn.core"):
             o = _decode_core(q, k, v, jnp.arange(k.shape[1]), positions, window,
                              scale, None)

@@ -103,12 +103,13 @@ def block_decode(
 
 def block_decode_paged(
     cfg: ModelConfig, p: Params, x: jax.Array, pk: jax.Array, pv: jax.Array,
-    table: jax.Array, positions: jax.Array, window: int
+    base: jax.Array, table: jax.Array, positions: jax.Array, window: int
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """``block_decode`` against one layer's paged block pools (per-slot positions)."""
+    """``block_decode`` against one layer's blocks of the flattened stacked
+    block pools, rows ``base + table`` (per-slot positions)."""
     h = Lyr.norm(cfg, p["ln1"], x)
     h, pk, pv = Lyr.attention_decode_paged(
-        cfg, p["attn"], h, pk, pv, table, positions, window=window)
+        cfg, p["attn"], h, pk, pv, base, table, positions, window=window)
     x = x + cfg.residual_scale * h
     h = Lyr.norm(cfg, p["ln2"], x)
     if "moe" in p:
@@ -694,9 +695,12 @@ class Model:
         token (B,) int32.  Returns (logits (B, V), new pools).  Slot →
         request mapping, admission, eviction and the block free list are the
         scheduler's problem — this step is pure fixed-shape array math, one
-        jit signature per batch-size bucket.  Like ``decode_step``, the
-        pools ride the scan carry with per-layer dynamic slices so buffer
-        donation keeps one pool-sized buffer live.  Dense/moe/vlm only.
+        jit signature per batch-size bucket.  The pools ride the scan carry
+        flattened to (L·num_blocks, bs, Hkv, hd) — merging the two leading
+        dims is a bitcast — and layer ``li`` appends into and gathers from
+        its rows ``li·num_blocks + table`` in place, so no layer's pool is
+        ever sliced out or written back and buffer donation keeps one
+        pool-sized buffer live.  Dense/moe/vlm only.
         """
         cfg = self.cfg
         if cfg.family not in ("dense", "moe", "vlm"):
@@ -706,27 +710,24 @@ class Model:
         pat = len(cfg.window_pattern)
         groups = cfg.num_layers // pat
         gp = _group(params["layers"], groups, pat)
+        shape = pools["k"].shape
+        num_blocks = shape[1]
 
         def gbody(carry, inp):
-            x, pool_k, pool_v = carry
+            x, pk, pv = carry
             lp, g = inp
             for j in range(pat):
                 with jax.named_scope("weights"):  # this layer's weight slabs
                     pj = jax.tree.map(lambda a, j=j: a[j], lp)
-                li = g * pat + j
-                with jax.named_scope("kv_pool.read"):
-                    kl = jax.lax.dynamic_index_in_dim(pool_k, li, 0, keepdims=False)
-                    vl = jax.lax.dynamic_index_in_dim(pool_v, li, 0, keepdims=False)
-                x, kl, vl = block_decode_paged(
-                    cfg, pj, x, kl, vl, table, positions,
-                    window=cfg.window_pattern[j],
+                x, pk, pv = block_decode_paged(
+                    cfg, pj, x, pk, pv, (g * pat + j) * num_blocks, table,
+                    positions, window=cfg.window_pattern[j],
                 )
-                with jax.named_scope("kv_pool.write"):
-                    pool_k = jax.lax.dynamic_update_index_in_dim(pool_k, kl, li, 0)
-                    pool_v = jax.lax.dynamic_update_index_in_dim(pool_v, vl, li, 0)
-            return (x, pool_k, pool_v), None
+            return (x, pk, pv), None
 
+        flat = (-1, *shape[2:])
         (x, nk, nv), _ = self._scan(
-            gbody, (x, pools["k"], pools["v"]), (gp, jnp.arange(groups)))
+            gbody, (x, pools["k"].reshape(flat), pools["v"].reshape(flat)),
+            (gp, jnp.arange(groups)))
         logits = self._logits(params, x)[:, 0]
-        return logits, {"k": nk, "v": nv}
+        return logits, {"k": nk.reshape(shape), "v": nv.reshape(shape)}

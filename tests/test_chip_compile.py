@@ -12,6 +12,9 @@ one process at a time may load the TPU library, and the test workers all
 import this file.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -26,7 +29,7 @@ from repro.core.dataflow import (
     hbm_traffic_bytes,
 )
 from repro.kernels import flex_linear, mha_flash, paged_attention
-from repro.models import get_config
+from repro.models import Model, get_config
 
 CFG = get_config("qwen3_4b")
 D, F, V = CFG.d_model, CFG.d_ff, CFG.padded_vocab
@@ -146,3 +149,54 @@ def test_cmu_analytical_plan_compiles(one_chip):
         for M, gp in [(PREFILL, lp), (BUCKET, lp.decode[BUCKET])]:
             _linear(one_chip, M, lp.gemm.K, lp.gemm.N, gp.dataflow,
                     gp.block, gp.strip)
+
+
+# ops that move a pool's blocks as a whole; an op with one of these opcodes
+# whose result holds more than one KV block copies (part of) a pool
+POOL_MOVES = ("copy", "copy-start", "copy-done", "dynamic-slice",
+              "dynamic-update-slice", "slice", "slice-start", "slice-done")
+INSTRUCTION = re.compile(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w\-]+)\(")
+
+
+@pytest.mark.parametrize("attn", ["xla", "pallas"])
+def test_paged_decode_step_copies_no_pool(one_chip, monkeypatch, attn):
+    """The scheduler's decode program, for two layers of qwen3_4b at the
+    decode cell's 48 slots and a pool of the cell's size (2,819 blocks, a
+    count no other op has, and too large for the compiler to keep in VMEM),
+    appends into and reads the stacked pools in place: no copy, slice or
+    update op of its HLO yields a layer's pool, a part of one, or the
+    stacked pools, and the pools come out aliased to the donated inputs."""
+    import repro.kernels.ops as ops
+    from repro.launch.scheduler import _jit_steps
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = CFG.replace(num_layers=2, use_pallas=True, tie_embeddings=True,
+                      attn_pallas=attn == "pallas")
+    model = Model(cfg)
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    slots, per_slot, bs, blocks = 48, 96, 16, 2819
+    row = (bs, HKV, HD)
+    pool = sds((cfg.num_layers, blocks, *row), jnp.bfloat16)
+    i32 = jnp.int32
+    _, decode = _jit_steps(model)
+    compiled = decode.lower(params, pool, pool, sds((slots, per_slot), i32),
+                            sds((slots,), i32), sds((slots,), i32),
+                            sds((slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    moves, scatters = [], set()
+    for name, result, op in INSTRUCTION.findall(text):
+        for dims in re.findall(r"\[([\d,]+)\]", result):
+            d = tuple(int(x) for x in dims.split(","))
+            if d[-3:] != row:
+                continue
+            if op in POOL_MOVES and math.prod(d[:-3]) > 1:
+                moves.append(f"{op} {name} {d}")
+            if op == "scatter":
+                scatters.add(d)
+    assert not moves, moves
+    # the new token's row lands in the flattened stacked pools themselves
+    assert (cfg.num_layers * blocks, *row) in scatters, scatters
+    pool_bytes = math.prod(pool.shape) * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes

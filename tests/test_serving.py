@@ -430,6 +430,78 @@ def test_scheduler_matches_sequential_with_pallas_attention():
 # ---------------------------------------------------------------------------
 
 
+def _decode_sliced_oracle(model, params, pools, table, positions, token):
+    """The paged decode step with each layer's pool sliced out of the
+    stacked pools, handed to the block, and written back whole."""
+    from repro.models.transformer import _group, block_decode_paged
+
+    cfg = model.cfg
+    x = params["embed"].astype(model.dtype)[token][:, None] * cfg.emb_scale
+    pat = len(cfg.window_pattern)
+    groups = cfg.num_layers // pat
+
+    def gbody(carry, inp):
+        x, pool_k, pool_v = carry
+        lp, g = inp
+        for j in range(pat):
+            pj = jax.tree.map(lambda a, j=j: a[j], lp)
+            li = g * pat + j
+            kl = jax.lax.dynamic_index_in_dim(pool_k, li, 0, keepdims=False)
+            vl = jax.lax.dynamic_index_in_dim(pool_v, li, 0, keepdims=False)
+            x, kl, vl = block_decode_paged(cfg, pj, x, kl, vl, 0, table,
+                                           positions,
+                                           window=cfg.window_pattern[j])
+            pool_k = jax.lax.dynamic_update_index_in_dim(pool_k, kl, li, 0)
+            pool_v = jax.lax.dynamic_update_index_in_dim(pool_v, vl, li, 0)
+        return (x, pool_k, pool_v), None
+
+    (x, nk, nv), _ = jax.lax.scan(
+        gbody, (x, pools["k"], pools["v"]),
+        (_group(params["layers"], groups, pat), jnp.arange(groups)))
+    return model._logits(params, x)[:, 0], {"k": nk, "v": nv}
+
+
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+@pytest.mark.parametrize("arch", ["qwen3_4b", "gemma3_12b"])
+def test_paged_decode_appends_into_the_stacked_pools_in_place(arch, path):
+    """``decode_step_paged`` addresses each layer's blocks inside the
+    stacked pools: the same logits and pools as slicing every layer's pool
+    out and writing it back, with only each live slot's new row and the
+    scratch blocks changed.  ``gemma3_12b`` runs two layers per scan group
+    (a sliding window, then global); ``pallas`` reads the pools through the
+    paged kernel instead of the gather."""
+    cfg = get_config(arch, smoke=True).replace(attn_pallas=path == "pallas")
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    bs, num_blocks = 16, 9
+    shape = (cfg.num_layers, num_blocks, bs, cfg.num_kv_heads, cfg.head_dim)
+    kk, kv = jax.random.split(jax.random.PRNGKey(1))
+    pools = {"k": jax.random.normal(kk, shape, jnp.bfloat16),
+             "v": jax.random.normal(kv, shape, jnp.bfloat16)}
+    # three live slots on blocks of their own, one pad slot on scratch
+    table = jnp.array([[1, 2], [3, 4], [5, 6], [SCRATCH_BLOCK] * 2], jnp.int32)
+    positions = jnp.array([5, 17, 30, 0], jnp.int32)
+    token = jnp.array([3, 7, 11, 0], jnp.int32)
+
+    logits, got = jax.jit(model.decode_step_paged)(
+        params, pools, table, positions, token)
+    want_logits, want = jax.jit(
+        lambda *a: _decode_sliced_oracle(model, *a))(
+        params, pools, table, positions, token)
+    np.testing.assert_array_equal(logits, want_logits)
+    live = [(int(table[b, p // bs]), p % bs)
+            for b, p in enumerate(np.asarray(positions[:3]))]
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(got[n], want[n])
+        changed = np.asarray(got[n] != pools[n]).any(axis=(3, 4))
+        allowed = np.zeros_like(changed)
+        allowed[:, SCRATCH_BLOCK] = True
+        for blk, off in live:
+            assert changed[:, blk, off].all()  # every layer took the row
+            allowed[:, blk, off] = True
+        assert not (changed & ~allowed).any()
+
+
 def test_paged_cache_geometry(smoke_model):
     cfg, _, _ = smoke_model
     kv = PagedKVCache(cfg, num_blocks=6, block_size=16)

@@ -21,8 +21,7 @@ CHILDREN = ("serve.admit", "serve.decode", "serve.sync", "serve.drain")
 PROJECTIONS = ("attn.wq", "attn.wk", "attn.wv", "attn.wo",
                "mlp.w1", "mlp.w2", "mlp.w3", "lm_head")
 SCOPES = {
-    "decode": PROJECTIONS + ("kv_pool.read", "kv_pool.write", "kv.append",
-                             "attn.kv_gather", "attn.core"),
+    "decode": PROJECTIONS + ("kv.append", "attn.kv_gather", "attn.core"),
     "prefill": PROJECTIONS + ("attn.core", "kv.scatter"),
 }
 
@@ -123,6 +122,18 @@ def test_compiled_steps_carry_every_scope(tiny, kind, path):
     # slice into the projection that reads it, so look before optimization
     lowered = _lowered(cfg, kind).as_text(debug_info=True)
     assert re.search(r'loc\("[^"]*weights/squeeze', lowered)
+
+
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_decode_step_slices_out_no_layer_pool(tiny, path):
+    """The paged decode step appends into and gathers from the stacked
+    pools in place: nothing in it is scoped as a layer pool's slice-out or
+    write-back any more."""
+    cfg = tiny[0].replace(use_pallas=path == "pallas")
+    text = _lowered(cfg, "decode").as_text(debug_info=True)
+    assert "kv.append" in text
+    assert "kv_pool." not in text
+    assert "kv_pool." not in _compiled_text(cfg, "decode")
 
 
 def _instructions(text: str) -> str:
