@@ -101,7 +101,7 @@ def bench_decode(shape, buckets, iters: int, interpret: bool) -> dict:
         q = jax.random.normal(kq, (b, shape.heads, shape.head_dim),
                               jnp.float32)
         pools = jax.random.normal(
-            kp, (2, b * nb + 1, bs, shape.kv_heads, shape.head_dim),
+            kp, (2, b * nb + 1, bs, shape.kv_heads * shape.head_dim),
             jnp.float32)
         table = 1 + jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
         positions = jnp.full((b,), cache_len - 1, jnp.int32)

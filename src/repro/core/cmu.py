@@ -1053,7 +1053,7 @@ def measure_attention_decode(
     kq, kp = jax.random.split(jax.random.PRNGKey(0))
     q = jax.random.normal(kq, (bucket, shape.heads, shape.head_dim), dtype)
     pools = jax.random.normal(
-        kp, (2, bucket * nb + 1, block_size, shape.kv_heads, shape.head_dim),
+        kp, (2, bucket * nb + 1, block_size, shape.kv_heads * shape.head_dim),
         dtype)
     table = 1 + jnp.arange(bucket * nb, dtype=jnp.int32).reshape(bucket, nb)
     positions = jnp.full((bucket,), cache_len - 1, jnp.int32)

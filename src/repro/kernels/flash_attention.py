@@ -302,11 +302,47 @@ def mha_flash(
              .reshape(B, S, H, hd))
 
 
+def heads_to_rows(q, hkv: int):
+    """Queries ``(B, H, hd)`` as block-diagonal rows ``(B, H, Hkv·hd)``:
+    row ``j·Hkv + h`` holds query ``j`` of KV head ``h`` in columns
+    ``h·hd … h·hd+hd−1`` and zeros elsewhere.  One product of these rows
+    with a K row (a position's heads side by side, as the paged pools hold
+    it) scores every head, and no head is ever split out of K: on the TPU
+    that split is a relayout of the whole view, padded to 128 lanes where
+    hd is 64.  The rows go group-major so that ``rows_to_heads`` reads the
+    product's rows ``(B, g, Hkv, ·)`` as a bitcast."""
+    B, H, hd = q.shape
+    own = jnp.eye(hkv, dtype=q.dtype)
+    qg = q.reshape(B, hkv, H // hkv, hd).swapaxes(1, 2)  # (B, g, Hkv, hd)
+    return (qg[:, :, :, None, :] * own[:, :, None]).reshape(B, H, hkv * hd)
+
+
+def rows_to_heads(x, hd: int):
+    """The inverse of ``heads_to_rows`` for a product with V's rows:
+    ``(B, H, Hkv·hd)`` -> ``(B, H, hd)`` in head order, each query head's
+    own hd columns (the other heads' columns are multiplied by exact
+    zeros)."""
+    B, H, C = x.shape
+    hkv = C // hd
+    own = jnp.repeat(jnp.eye(hkv, dtype=x.dtype), hd, axis=1)  # (Hkv, Hkv·hd)
+    o = (x.reshape(B, H // hkv, hkv, C) * own).sum(2)  # (B, g, Hkv·hd)
+    return o.reshape(B, H // hkv, hkv, hd).swapaxes(1, 2).reshape(B, H, hd)
+
+
+def row_values_to_heads(v, hkv: int):
+    """A value per row of ``heads_to_rows`` ``(B, H)``, in head order."""
+    B, H = v.shape
+    return v.reshape(B, H // hkv, hkv).swapaxes(1, 2).reshape(B, H)
+
+
 def _paged_decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, scale, window, bs, group):
-    """Grid (B, nb): one decode slot's query heads resident; K/V blocks
-    stream straight out of the paged pools (the scalar-prefetched block
-    table picks the pool row per grid step — no dense gather copy).
+                         acc_ref, m_ref, l_ref, *, scale, window, bs):
+    """Grid (B, nb): one decode slot's query heads resident, as
+    block-diagonal rows (``heads_to_rows``); K/V blocks stream straight out
+    of the paged pools (the scalar-prefetched block table picks the pool
+    row per grid step — no dense gather copy), ``(bs, Hkv·hd)`` as the
+    pools hold them.  Each product runs over whole rows; the wrapper keeps
+    each head's own columns of the output.
 
     Masked probabilities are zeroed *multiplicatively*: with a sliding
     window the leading blocks of a deep sequence can be fully masked,
@@ -324,33 +360,30 @@ def _paged_decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32)      # (H, hd)
-    k = k_ref[0].astype(jnp.float32)      # (bs, Hkv, hd)
+    q = q_ref[0].astype(jnp.float32)      # (H, Hkv·hd), block-diagonal
+    k = k_ref[0].astype(jnp.float32)      # (bs, Hkv·hd)
     v = v_ref[0].astype(jnp.float32)
-    hkv = k.shape[1]
-    qg = q.reshape(hkv, group, q.shape[-1])
-    s = jnp.einsum("hgd,khd->hgk", qg, k,
-                   preferred_element_type=jnp.float32) * scale
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
     kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
     pos = pos_ref[b]
     live = kpos <= pos
     if window:
         live = live & (pos - kpos < window)
-    live = live[None, None, :]
+    live = live[None, :]
     s = jnp.where(live, s, _NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.where(live, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
-        "hgk,khd->hgd", p, v, preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
-        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = o.reshape(o_ref.shape[1], o_ref.shape[2]).astype(
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
             o_ref.dtype)
 
 
@@ -360,44 +393,43 @@ def paged_attention(q, pool_k, pool_v, table, positions, *,
     """Decode-shaped skinny-q attention reading K/V blocks in place.
 
     ``q``: (B, H, hd) — one new token per slot.  ``pool_k/v``:
-    (num_blocks, bs, Hkv, hd) paged pools.  ``table``: (B, nb) int32 block
-    table; ``positions``: (B,) int32 current position per slot.  Each slot
-    computes independently, so pad slots (all-scratch tables, position 0)
-    cannot perturb live rows — the scheduler's bucket-padding contract.
-    Returns (B, H, hd) in ``q.dtype``.
+    (num_blocks, bs, Hkv·hd) paged pools, a position's heads side by side.
+    ``table``: (B, nb) int32 block table; ``positions``: (B,) int32 current
+    position per slot.  Each slot computes independently, so pad slots
+    (all-scratch tables, position 0) cannot perturb live rows — the
+    scheduler's bucket-padding contract.  Returns (B, H, hd) in
+    ``q.dtype``.
     """
     B, H, hd = q.shape
-    bs, Hkv = pool_k.shape[1], pool_k.shape[2]
+    bs, C = pool_k.shape[1], pool_k.shape[2]
     nb = table.shape[1]
-    group = H // Hkv
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               window=window, bs=bs, group=group)
+                               window=window, bs=bs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, j, tbl, ps: (b, 0, 0)),
-            pl.BlockSpec((1, bs, Hkv, hd),
-                         lambda b, j, tbl, ps: (tbl[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, Hkv, hd),
-                         lambda b, j, tbl, ps: (tbl[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, H, C), lambda b, j, tbl, ps: (b, 0, 0)),
+            pl.BlockSpec((1, bs, C), lambda b, j, tbl, ps: (tbl[b, j], 0, 0)),
+            pl.BlockSpec((1, bs, C), lambda b, j, tbl, ps: (tbl[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, j, tbl, ps: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, C), lambda b, j, tbl, ps: (b, 0, 0)),
         scratch_shapes=[
-            _VMEM((Hkv, group, hd), jnp.float32),
-            _VMEM((Hkv, group, 1), jnp.float32),
-            _VMEM((Hkv, group, 1), jnp.float32),
+            _VMEM((H, C), jnp.float32),
+            _VMEM((H, 1), jnp.float32),
+            _VMEM((H, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    rows = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, C), q.dtype),
         compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(table, positions, q, pool_k, pool_v)
+    )(table, positions, heads_to_rows(q, C // hd), pool_k, pool_v)
+    return rows_to_heads(rows, hd)
 
 
 def paged_attention_reference(q, pool_k, pool_v, table, positions, *,
@@ -407,7 +439,7 @@ def paged_attention_reference(q, pool_k, pool_v, table, positions, *,
     decode kind the CMU times against the paged kernel, and the oracle the
     property sweep checks it against."""
     B, H, hd = q.shape
-    Hkv = pool_k.shape[2]
+    Hkv = pool_k.shape[2] // hd
     group = H // Hkv
     if scale is None:
         scale = 1.0 / math.sqrt(hd)

@@ -159,6 +159,10 @@ class ServeStats:
     timeouts: int = 0
     failures: int = 0
     faults_injected: dict[str, int] = field(default_factory=dict)
+    # the KV pools as built: K and V's device bytes (padding included) and
+    # the positions they hold (num_blocks × block_size)
+    kv_pool_bytes: int = 0
+    kv_pool_positions: int = 0
     active_per_step: list[int] = field(default_factory=list)
     bucket_per_step: list[int] = field(default_factory=list)
     # (decode steps so far, tokens so far, perf_counter) at every sync event
@@ -318,6 +322,7 @@ class ServeScheduler:
         if num_blocks is None:
             num_blocks = capacity * self.max_blocks + 1  # +1 scratch
         self.kv = PagedKVCache(cfg, num_blocks, block_size)
+        self.kv_pool_bytes = self.kv.device_bytes
         if faults is not None:
             self.kv.allocator.fault_hook = faults.fail_alloc
 
@@ -348,7 +353,9 @@ class ServeScheduler:
     # -- the loop ----------------------------------------------------------
 
     def run(self, requests: list[Request]) -> tuple[dict[int, RequestResult], ServeStats]:
-        stats = ServeStats(capacity=self.capacity)
+        stats = ServeStats(capacity=self.capacity,
+                           kv_pool_bytes=self.kv_pool_bytes,
+                           kv_pool_positions=self.kv.positions)
         with stats.span("serve.run"):
             results = self._run(requests, stats)
         return results, stats

@@ -34,6 +34,7 @@ class ModelConfig:
     # residual-stream scaling (minicpm / gemma)
     emb_scale: float = 1.0          # multiply token embeddings
     residual_scale: float = 1.0     # multiply block outputs (minicpm depth-scale)
+    logit_divisor: float = 1.0      # divide the f32 logits (minicpm: d_model / dim_model_base)
 
     # MoE
     num_experts: int = 0

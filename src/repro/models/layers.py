@@ -555,40 +555,54 @@ def _attention_full_core(cfg: ModelConfig, q, k, v, core: dict) -> jax.Array:
 def _decode_core(q, k, v, kpos, pos, window: int, scale: float, axis: str | None):
     """Flash-style decode attention over a (possibly seq-sharded) cache.
 
-    q (B,1,H,hd); k/v (B,Sloc,Hkv,hd) local shard; kpos global key positions.
+    q (B,1,H,hd); k/v (B,Sloc,Hkv·hd) local shard, a position's heads side
+    by side as the paged pools hold them; kpos global key positions.
     ``pos`` is a scalar (whole batch at one position) or a (B,) vector of
     per-slot positions (the continuous-batching paged path, where every slot
     is at a different depth in its own stream).
     With ``axis`` set (inside shard_map) the softmax is distributed:
     pmax for the max, psum for numerator/denominator — so a 32k..500k cache
     never gets gathered (observed: 40GB/step of cache all-gathers before).
+
+    Heads are never split out of K/V's rows: the scores are one product of
+    the block-diagonal query rows (``heads_to_rows``) with K, the output one
+    product of the probabilities with V, of which each head keeps its own
+    columns (``rows_to_heads``).  Both take their operands in the cache's
+    dtype and accumulate in f32 (what the TPU's default precision computes
+    for f32 operands), so no f32 copy of the cache is ever made.
     """
+    from repro.kernels.flash_attention import (
+        heads_to_rows,
+        row_values_to_heads,
+        rows_to_heads,
+    )
+
     B, _, H, hd = q.shape
-    Hkv = k.shape[2]
-    g = H // Hkv
-    qg = q.reshape(B, 1, Hkv, g, hd)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32), k.astype(jnp.float32))
+    Hkv = k.shape[-1] // hd
+    q_rows = heads_to_rows(q[:, 0].astype(k.dtype), Hkv)
+    s = jnp.einsum("bnc,bkc->bnk", q_rows, k, preferred_element_type=jnp.float32)
     s = s * scale
     if getattr(pos, "ndim", 0):
         m = kpos[None, :] <= pos[:, None]
         if window:
             m = m & ((pos[:, None] - kpos[None, :]) < window)
-        s = jnp.where(m[:, None, None, None, :], s, -1e30)
+        s = jnp.where(m[:, None, :], s, -1e30)
     else:
         m = kpos <= pos
         if window:
             m = m & ((pos - kpos) < window)
-        s = jnp.where(m[None, None, None, None, :], s, -1e30)
+        s = jnp.where(m[None, None, :], s, -1e30)
     mx = jnp.max(s, axis=-1, keepdims=True)
     if axis is not None:
         mx = jax.lax.pmax(mx, axis)
     pr = jnp.exp(s - mx)
-    num = jnp.einsum("bhgqk,bkhd->bqhgd", pr, v.astype(jnp.float32))
-    den = jnp.sum(pr, axis=-1)  # (B,Hkv,g,1)
+    num = rows_to_heads(jnp.einsum("bnk,bkc->bnc", pr.astype(v.dtype), v,
+                                   preferred_element_type=jnp.float32), hd)
+    den = row_values_to_heads(jnp.sum(pr, axis=-1), Hkv)  # (B,H)
     if axis is not None:
         num = jax.lax.psum(num, axis)
         den = jax.lax.psum(den, axis)
-    o = num / jnp.maximum(den, 1e-30).transpose(0, 3, 1, 2)[..., None]
+    o = num / jnp.maximum(den, 1e-30)[..., None]
     return o.reshape(B, 1, H, hd).astype(q.dtype)
 
 
@@ -621,7 +635,9 @@ def attention_decode(
     if mesh is None or ext <= 1 or Smax % ext or Hkv % ext == 0:
         # single-device, or the cache is head-sharded (divisible kv heads)
         with jax.named_scope("attn.core"):
-            o = _decode_core(q, k, v, jnp.arange(Smax), pos, window, scale, None)
+            o = _decode_core(q, k.reshape(B, Smax, cfg.kv_dim),
+                             v.reshape(B, Smax, cfg.kv_dim), jnp.arange(Smax),
+                             pos, window, scale, None)
     else:
         from jax.sharding import PartitionSpec as P
 
@@ -632,7 +648,9 @@ def attention_decode(
         def local_fn(q_l, k_l, v_l, pos_l):
             idx = jax.lax.axis_index(seq_ax)
             kpos = idx * Sloc + jnp.arange(Sloc)
-            return _decode_core(q_l, k_l, v_l, kpos, pos_l, window, scale, seq_ax)
+            flat = (*k_l.shape[:2], -1)
+            return _decode_core(q_l, k_l.reshape(flat), v_l.reshape(flat), kpos,
+                                pos_l, window, scale, seq_ax)
 
         with jax.named_scope("attn.core"):
             o = jax.shard_map(
@@ -667,13 +685,14 @@ def attention_decode_paged(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode against one layer's blocks of the stacked KV pools.
 
-    x (B,1,D); pk/pv (L·num_blocks, bs, Hkv, hd) — every layer's block
+    x (B,1,D); pk/pv (L·num_blocks, bs, Hkv·hd) — every layer's block
     pools, flattened so that this layer's block ``b`` is row ``base + b``;
     table (B, nb) int32 per-slot block tables; positions (B,) per-slot write
     positions (= tokens already cached for that slot).  The new K/V lands at
     ``(base + table[pos // bs], pos % bs)`` per slot, in place, then
     attention runs over the gathered dense view of each slot's table with
-    the per-slot causal mask of ``_decode_core``.  Pad slots of a bucketed
+    the per-slot causal mask of ``_decode_core``, which reads the view in
+    the pools' rows, heads side by side.  Pad slots of a bucketed
     batch point their whole table at the reserved scratch block, so their
     writes land in this layer's scratch block, never in a live request's
     blocks, and their garbage reads are masked to exact zeros.
@@ -684,13 +703,12 @@ def attention_decode_paged(
         q = rope(q, positions[:, None], cfg.rope_theta)
         k_new = rope(k_new, positions[:, None], cfg.rope_theta)
     bs = pk.shape[1]
-    Hkv, hd = pk.shape[2], pk.shape[3]
     rows = base + table  # this layer's blocks, as rows of the stacked pools
     with jax.named_scope("kv.append"):
         blk = jnp.take_along_axis(rows, (positions // bs)[:, None], axis=1)[:, 0]
         off = positions % bs
-        pk = pk.at[blk, off].set(k_new[:, 0].astype(pk.dtype))
-        pv = pv.at[blk, off].set(v_new[:, 0].astype(pv.dtype))
+        pk = pk.at[blk, off].set(k_new.reshape(B, cfg.kv_dim).astype(pk.dtype))
+        pv = pv.at[blk, off].set(v_new.reshape(B, cfg.kv_dim).astype(pv.dtype))
     scale = 1.0 / math.sqrt(cfg.head_dim)
     if cfg.attn_pallas and _attn_decode_kind(B) == "paged":
         # in-place Pallas kernel: K/V blocks stream straight out of the
@@ -706,8 +724,8 @@ def attention_decode_paged(
         # dense per-slot view: gathered entry j is the slot's logical
         # position j
         with jax.named_scope("attn.kv_gather"):
-            k = pk[rows].reshape(B, -1, Hkv, hd)
-            v = pv[rows].reshape(B, -1, Hkv, hd)
+            k = pk[rows].reshape(B, -1, cfg.kv_dim)
+            v = pv[rows].reshape(B, -1, cfg.kv_dim)
         with jax.named_scope("attn.core"):
             o = _decode_core(q, k, v, jnp.arange(k.shape[1]), positions, window,
                              scale, None)

@@ -225,8 +225,10 @@ class Model:
         h = Lyr.norm(cfg, params["final_norm"], h)
         wout = params.get("lm_head")
         if wout is None:
-            wout = params["embed"].T / max(cfg.emb_scale, 1.0)
+            wout = params["embed"].T
         logits = Lyr.linear(cfg, h, wout, name="lm_head").astype(jnp.float32)
+        if cfg.logit_divisor != 1.0:
+            logits = logits / cfg.logit_divisor
         if cfg.logit_softcap:
             logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
         # vocab (not seq) carries the 'model' axis here — the two must not collide
@@ -404,7 +406,8 @@ class Model:
 
     def prefill_kv(self, params, batch):
         """Forward the prompt and return ``(logits, k_all, v_all)`` with
-        k/v stacked ``(L, B, Sp, Hkv, hd)`` bf16 — no cache layout imposed.
+        k/v stacked ``(L, B, Sp, Hkv·hd)`` bf16 — a position's heads side by
+        side, as the projection yields them and the paged pools hold them.
 
         This is the layout-agnostic half of prefill: ``_prefill_dense``
         copies the K/V into a dense ``(L, B, cache_len, ...)`` cache, while
@@ -438,13 +441,17 @@ class Model:
                 q, k, v = Lyr._project_qkv(cfg, pj["attn"], h)
                 k = Lyr.rope(k, jnp.arange(Sp), cfg.rope_theta)
                 ks.append(k.astype(jnp.bfloat16))
-                vs.append(v.astype(jnp.bfloat16))
+                vs.append(v.reshape(B, Sp, cfg.kv_dim).astype(jnp.bfloat16))
                 x, _ = block_apply(cfg, pj, x, cfg.window_pattern[j], prefix)
             return (x,), (jnp.stack(ks), jnp.stack(vs))
 
         (x,), (k_all, v_all) = self._scan(body, (x,), (gp, jnp.arange(cfg.num_layers // pat)))
-        k_all = k_all.reshape(cfg.num_layers, B, Sp, cfg.num_kv_heads, cfg.head_dim)
-        v_all = v_all.reshape(cfg.num_layers, B, Sp, cfg.num_kv_heads, cfg.head_dim)
+        # K leaves the layer scan in rope's per-head layout and is flattened
+        # here, once for all layers: flattening it inside the scan splits
+        # rope's fusion into half-head pieces, which on the TPU cost a
+        # 2048-token prefill ~3% (V is flat as the projection yields it)
+        k_all = k_all.reshape(cfg.num_layers, B, Sp, cfg.kv_dim)
+        v_all = v_all.reshape(cfg.num_layers, B, Sp, cfg.kv_dim)
         # logits come off the same pass: the scan's x walks through the exact
         # ``block_apply`` sequence ``forward`` uses, so final-norm + lm_head
         # here is bitwise-identical to a separate forward — at half the cost.
@@ -454,7 +461,9 @@ class Model:
     def _prefill_dense(self, params, batch, cache_len):
         cfg = self.cfg
         logits, k_all, v_all = self.prefill_kv(params, batch)
-        B, Sp = k_all.shape[1], k_all.shape[2]
+        L, B, Sp = k_all.shape[:3]
+        heads = (L, B, Sp, cfg.num_kv_heads, cfg.head_dim)
+        k_all, v_all = k_all.reshape(heads), v_all.reshape(heads)
         cache = Lyr.init_kv_cache(cfg, B, cache_len)
         cache["k"] = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_all, 0, axis=2)
         cache["v"] = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_all, 0, axis=2)
@@ -690,13 +699,13 @@ class Model:
     def decode_step_paged(self, params: Params, pools, table, positions, token: jax.Array):
         """One continuous-batching decode step over the paged KV block pools.
 
-        pools {"k","v"}: (L, num_blocks, bs, Hkv, hd); table (B, nb) int32
+        pools {"k","v"}: (L, num_blocks, bs, Hkv·hd); table (B, nb) int32
         per-slot block tables; positions (B,) int32 per-slot write positions;
         token (B,) int32.  Returns (logits (B, V), new pools).  Slot →
         request mapping, admission, eviction and the block free list are the
         scheduler's problem — this step is pure fixed-shape array math, one
         jit signature per batch-size bucket.  The pools ride the scan carry
-        flattened to (L·num_blocks, bs, Hkv, hd) — merging the two leading
+        flattened to (L·num_blocks, bs, Hkv·hd) — merging the two leading
         dims is a bitcast — and layer ``li`` appends into and gathers from
         its rows ``li·num_blocks + table`` in place, so no layer's pool is
         ever sliced out or written back and buffer donation keeps one

@@ -7,8 +7,11 @@ fixed batch can never be backfilled mid-flight.  This module replaces it
 with the paged layout production servers use (vLLM's PagedAttention):
 
   * the cache is a pool of fixed-size **blocks** —
-    ``(L, num_blocks, block_size, Hkv, hd)`` per K and V — allocated to
-    requests in ``block_size``-token units by a host-side free list
+    ``(L, num_blocks, block_size, Hkv·hd)`` per K and V, a position's heads
+    side by side in one row, so that the two minor dimensions fill whole
+    (sublane, lane) tiles on the TPU for any head size (a ``(…, Hkv, hd)``
+    block pads heads of 64 to 128 lanes) — allocated to requests in
+    ``block_size``-token units by a host-side free list
     (``BlockAllocator``);
   * each request owns a **block table** (its ordered block ids); logical
     position ``p`` of a request lives at ``(table[p // bs], p % bs)``;
@@ -99,22 +102,29 @@ class BlockAllocator:
 class PagedKVCache:
     """The device pools + the host allocator, sized for one serving run.
 
-    ``k`` / ``v`` are ``(L, num_blocks, block_size, Hkv, hd)`` bf16 — the
-    serving dtype of the dense cache, block-paged.  The pools are plain
-    arrays the caller threads through the jitted prefill/decode steps
-    (donated, so updates are in-place); this object only tracks allocator
-    state between steps.
+    ``k`` / ``v`` are ``(L, num_blocks, block_size, Hkv·hd)`` bf16 — the
+    serving dtype of the dense cache, block-paged, heads flattened into the
+    minor dimension.  The pools are plain arrays the caller threads through
+    the jitted prefill/decode steps (donated, so updates are in-place); this
+    object only tracks allocator state between steps.  ``device_bytes`` is
+    what K and V take on the device, padding included; ``positions`` is
+    ``num_blocks × block_size``.
     """
 
     def __init__(self, cfg, num_blocks: int, block_size: int,
                  layers: int | None = None):
         L = layers if layers is not None else cfg.num_layers
-        shape = (L, num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
+        shape = (L, num_blocks, block_size, cfg.kv_dim)
         self.k = jnp.zeros(shape, jnp.bfloat16)
         self.v = jnp.zeros(shape, jnp.bfloat16)
         self.block_size = block_size
         self.num_blocks = num_blocks
+        self.positions = num_blocks * block_size
         self.allocator = BlockAllocator(num_blocks, reserved=1)
+
+    @property
+    def device_bytes(self) -> int:
+        return self.k.on_device_size_in_bytes() + self.v.on_device_size_in_bytes()
 
     def blocks_for(self, tokens: int) -> int:
         """Blocks needed to cache ``tokens`` positions."""
@@ -132,16 +142,16 @@ class PagedKVCache:
 def write_prefill_blocks(pool_k, pool_v, k_all, v_all, table):
     """Scatter a prefill's per-layer K/V into the block pools.
 
-    ``k_all`` / ``v_all``: (L, B, S, Hkv, hd) with S a multiple of the
-    block size; ``table``: (B, S // bs) int32 block ids per row.  Table
-    entries beyond a request's allocation point at the scratch block —
-    their (pad-position) K/V lands there and is never read unmasked.
-    Returns the updated pools (pure; callers jit with donation).
+    ``k_all`` / ``v_all``: (L, B, S, Hkv·hd), the pools' row layout, with S
+    a multiple of the block size; ``table``: (B, S // bs) int32 block ids
+    per row.  Table entries beyond a request's allocation point at the
+    scratch block — their (pad-position) K/V lands there and is never read
+    unmasked.  Returns the updated pools (pure; callers jit with donation).
     """
-    L, B, S = k_all.shape[:3]
+    L, B, S, C = k_all.shape
     bs = pool_k.shape[2]
     nb = S // bs
     with jax.named_scope("kv.scatter"):
-        k_r = k_all.reshape(L, B, nb, bs, *k_all.shape[3:]).astype(pool_k.dtype)
-        v_r = v_all.reshape(L, B, nb, bs, *v_all.shape[3:]).astype(pool_v.dtype)
+        k_r = k_all.reshape(L, B, nb, bs, C).astype(pool_k.dtype)
+        v_r = v_all.reshape(L, B, nb, bs, C).astype(pool_v.dtype)
         return pool_k.at[:, table].set(k_r), pool_v.at[:, table].set(v_r)

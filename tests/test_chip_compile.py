@@ -4,8 +4,9 @@ The TPU compiler is installed even where no chip is attached: it compiles
 for a described ``v5e:2x2`` topology and refuses what the chip would
 refuse — VMEM over the scoped limit, unaligned tiles, a kernel that cannot
 be lowered.  Interpret mode sees none of this.  Every case compiles one
-kernel at qwen3_4b's published widths (d_model 2560, d_ff 9728, 32/8 GQA
-heads of 128, vocabulary 152064 padded) and nothing runs.
+kernel or step at qwen3_4b's published widths (d_model 2560, d_ff 9728,
+32/8 GQA heads of 128, vocabulary 152064 padded), or where it says so at
+minicpm_2b's (d_model 2304, 36 MHA heads of 64), and nothing runs.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the test workers all
@@ -131,13 +132,24 @@ def test_flash_prefill_compiles(one_chip, sweep):
              ((1, PREFILL, HKV, HD), bf))
 
 
-def test_paged_decode_compiles(one_chip):
+def _paged_kernel(one_chip, heads, kv_heads, hd):
     bs, nb, blocks = 16, 17, 4 * 17 + 1
     bf, i32 = jnp.bfloat16, jnp.int32
     _compile(lambda q, pk, pv, t, p: paged_attention(q, pk, pv, t, p,
                                                      interpret=False),
-             one_chip, ((BUCKET, H, HD), bf), ((blocks, bs, HKV, HD), bf),
-             ((blocks, bs, HKV, HD), bf), ((BUCKET, nb), i32), ((BUCKET,), i32))
+             one_chip, ((BUCKET, heads, hd), bf), ((blocks, bs, kv_heads * hd), bf),
+             ((blocks, bs, kv_heads * hd), bf), ((BUCKET, nb), i32),
+             ((BUCKET,), i32))
+
+
+def test_paged_decode_compiles(one_chip):
+    _paged_kernel(one_chip, H, HKV, HD)
+
+
+def test_paged_decode_compiles_at_heads_of_64(one_chip):
+    """minicpm_2b's 36 MHA heads of 64: the kernel reads the pools' rows
+    whole, so no block is split into heads narrower than a lane tile."""
+    _paged_kernel(one_chip, 36, 36, 64)
 
 
 def test_cmu_analytical_plan_compiles(one_chip):
@@ -158,6 +170,60 @@ POOL_MOVES = ("copy", "copy-start", "copy-done", "dynamic-slice",
 INSTRUCTION = re.compile(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w\-]+)\(")
 
 
+def _paged_decode_program(one_chip, monkeypatch, cfg, slots, blocks,
+                          per_slot=96, bs=16):
+    """The scheduler's decode program for ``cfg`` at ``slots`` slots, each
+    with a table of ``per_slot`` blocks, over a pool of ``blocks`` blocks
+    of ``bs`` positions, compiled for the described chip."""
+    import repro.kernels.ops as ops
+    from repro.launch.scheduler import _jit_steps
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    model = Model(cfg)
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = sds((cfg.num_layers, blocks, bs, cfg.kv_dim), jnp.bfloat16)
+    i32 = jnp.int32
+    _, decode = _jit_steps(model)
+    compiled = decode.lower(params, pool, pool, sds((slots, per_slot), i32),
+                            sds((slots,), i32), sds((slots,), i32),
+                            sds((slots,), jnp.bool_)).compile()
+    return compiled, pool
+
+
+def _results(text, *, buffers=False):
+    """(op, instruction name, dtype, dims) of every array an instruction
+    yields; with ``buffers``, only those of the program's own computations
+    (an instruction inside a fusion's computation is no buffer)."""
+    out, fused = [], False
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            fused = line.lstrip("%").startswith(("fused", "wrapped"))
+            continue
+        m = INSTRUCTION.match(line)
+        if m is None or (fused and buffers):
+            continue
+        name, result, op = m.groups()
+        for dt, dims in re.findall(r"(\w+)\[([\d,]+)\]", result):
+            out.append((op, name, dt, tuple(int(x) for x in dims.split(","))))
+    return out
+
+
+def _pool_moves(text, row):
+    """The copy, slice and update ops whose result holds more than one
+    block of ``row``, and the shapes scattered into."""
+    moves, scatters = [], set()
+    for op, name, _, d in _results(text):
+        if d[-len(row):] != row:
+            continue
+        if op in POOL_MOVES and math.prod(d[:-len(row)]) > 1:
+            moves.append(f"{op} {name} {d}")
+        if op == "scatter":
+            scatters.add(d)
+    return moves, scatters
+
+
 @pytest.mark.parametrize("attn", ["xla", "pallas"])
 def test_paged_decode_step_copies_no_pool(one_chip, monkeypatch, attn):
     """The scheduler's decode program, for two layers of qwen3_4b at the
@@ -166,37 +232,39 @@ def test_paged_decode_step_copies_no_pool(one_chip, monkeypatch, attn):
     appends into and reads the stacked pools in place: no copy, slice or
     update op of its HLO yields a layer's pool, a part of one, or the
     stacked pools, and the pools come out aliased to the donated inputs."""
-    import repro.kernels.ops as ops
-    from repro.launch.scheduler import _jit_steps
-
-    monkeypatch.setattr(ops, "default_interpret", lambda: False)
     cfg = CFG.replace(num_layers=2, use_pallas=True, tie_embeddings=True,
                       attn_pallas=attn == "pallas")
-    model = Model(cfg)
-    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
-    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
-                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    slots, per_slot, bs, blocks = 48, 96, 16, 2819
-    row = (bs, HKV, HD)
-    pool = sds((cfg.num_layers, blocks, *row), jnp.bfloat16)
-    i32 = jnp.int32
-    _, decode = _jit_steps(model)
-    compiled = decode.lower(params, pool, pool, sds((slots, per_slot), i32),
-                            sds((slots,), i32), sds((slots,), i32),
-                            sds((slots,), jnp.bool_)).compile()
-    text = compiled.as_text()
-    moves, scatters = [], set()
-    for name, result, op in INSTRUCTION.findall(text):
-        for dims in re.findall(r"\[([\d,]+)\]", result):
-            d = tuple(int(x) for x in dims.split(","))
-            if d[-3:] != row:
-                continue
-            if op in POOL_MOVES and math.prod(d[:-3]) > 1:
-                moves.append(f"{op} {name} {d}")
-            if op == "scatter":
-                scatters.add(d)
+    compiled, pool = _paged_decode_program(one_chip, monkeypatch, cfg, 48, 2819)
+    row = (16, HKV * HD)
+    moves, scatters = _pool_moves(compiled.as_text(), row)
     assert not moves, moves
     # the new token's row lands in the flattened stacked pools themselves
-    assert (cfg.num_layers * blocks, *row) in scatters, scatters
+    assert (cfg.num_layers * 2819, *row) in scatters, scatters
     pool_bytes = math.prod(pool.shape) * 2
     assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes
+
+
+def test_mha_decode_step_holds_no_f32_kv(one_chip, monkeypatch):
+    """Two layers of minicpm_2b (36 MHA heads of 64) at the decode cell's
+    24 slots and pool (1,409 blocks): heads of 64 are never split out of
+    the pools' rows nor widened to f32, so no buffer of the program is an
+    f32 tensor as large as the gathered K (or V) view, every buffer of
+    pool rows is unpadded ``(16, 2304)``, and no op copies a pool."""
+    from repro.models import get_config
+
+    cfg = get_config("minicpm_2b").replace(num_layers=2, use_pallas=True,
+                                           param_dtype="bfloat16")
+    slots, per_slot, bs = 24, 96, 16
+    compiled, pool = _paged_decode_program(one_chip, monkeypatch, cfg, slots,
+                                           1409, per_slot, bs)
+    text = compiled.as_text()
+    gathered = slots * per_slot * bs * cfg.kv_dim
+    wide = [(op, name, d) for op, name, dt, d in _results(text, buffers=True)
+            if dt == "f32" and math.prod(d) >= gathered]
+    assert not wide, wide
+    row = (bs, cfg.kv_dim)
+    moves, scatters = _pool_moves(text, row)
+    assert not moves, moves
+    assert (cfg.num_layers * 1409, *row) in scatters, scatters
+    assert re.search(r"bf16\[2304,16,2304\]\{2,1,0:T\(8,128\)\(2,1\)\}[^\n]*"
+                     r"attn\.kv_gather", text)

@@ -183,8 +183,8 @@ def _paged_case(B=3, H=4, Hkv=2, hd=32, bs=16, nb=4, seed=0):
     rng = np.random.default_rng(seed)
     num_blocks = 1 + B * nb
     q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    pk = jnp.asarray(rng.normal(size=(num_blocks, bs, Hkv, hd)), jnp.float32)
-    pv = jnp.asarray(rng.normal(size=(num_blocks, bs, Hkv, hd)), jnp.float32)
+    pk = jnp.asarray(rng.normal(size=(num_blocks, bs, Hkv * hd)), jnp.float32)
+    pv = jnp.asarray(rng.normal(size=(num_blocks, bs, Hkv * hd)), jnp.float32)
     table = 1 + jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
     # ragged positions: each slot at a different depth, none block-aligned
     positions = jnp.asarray([bs * nb - 1, 5, 2 * bs + 3][:B], jnp.int32)

@@ -12,6 +12,7 @@ touching their measured forward rows, and the pallas dispatch actually
 consults the bucket plans."""
 
 import json
+import math
 import os
 import tempfile
 
@@ -474,7 +475,7 @@ def test_paged_decode_appends_into_the_stacked_pools_in_place(arch, path):
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     bs, num_blocks = 16, 9
-    shape = (cfg.num_layers, num_blocks, bs, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, num_blocks, bs, cfg.kv_dim)
     kk, kv = jax.random.split(jax.random.PRNGKey(1))
     pools = {"k": jax.random.normal(kk, shape, jnp.bfloat16),
              "v": jax.random.normal(kv, shape, jnp.bfloat16)}
@@ -493,7 +494,7 @@ def test_paged_decode_appends_into_the_stacked_pools_in_place(arch, path):
             for b, p in enumerate(np.asarray(positions[:3]))]
     for n in ("k", "v"):
         np.testing.assert_array_equal(got[n], want[n])
-        changed = np.asarray(got[n] != pools[n]).any(axis=(3, 4))
+        changed = np.asarray(got[n] != pools[n]).any(axis=3)
         allowed = np.zeros_like(changed)
         allowed[:, SCRATCH_BLOCK] = True
         for blk, off in live:
@@ -502,10 +503,120 @@ def test_paged_decode_appends_into_the_stacked_pools_in_place(arch, path):
         assert not (changed & ~allowed).any()
 
 
+def _decode_head_layout_oracle(model, params, pools, table, positions, token):
+    """The paged decode step on pools laid out ``(L, num_blocks, bs, Hkv,
+    hd)``, a position's heads in a dimension of their own: each layer's
+    pool sliced out, the new row appended and every slot's table gathered
+    in that layout, then written back whole."""
+    from repro.models import layers as Lyr
+    from repro.models.transformer import _group
+
+    cfg = model.cfg
+    B, bs = token.shape[0], pools["k"].shape[2]
+    x = params["embed"].astype(model.dtype)[token][:, None] * cfg.emb_scale
+
+    def attention(p, h, pk, pv, window):
+        q, k, v = Lyr._project_qkv(cfg, p, h)
+        q = Lyr.rope(q, positions[:, None], cfg.rope_theta)
+        k = Lyr.rope(k, positions[:, None], cfg.rope_theta)
+        blk = table[jnp.arange(B), positions // bs]
+        pk = pk.at[blk, positions % bs].set(k[:, 0].astype(pk.dtype))
+        pv = pv.at[blk, positions % bs].set(v[:, 0].astype(pv.dtype))
+        kd, vd = pk[table], pv[table]  # (B, nb, bs, Hkv, hd)
+        o = Lyr._decode_core(q, kd.reshape(B, -1, cfg.kv_dim),
+                             vd.reshape(B, -1, cfg.kv_dim),
+                             jnp.arange(kd.shape[1] * bs), positions, window,
+                             1.0 / math.sqrt(cfg.head_dim), None)
+        return (Lyr.linear(cfg, o.reshape(B, 1, cfg.q_dim), p["wo"],
+                           name="attn.wo"), pk, pv)
+
+    def gbody(carry, lp):
+        x, pool_k, pool_v = carry
+        li, lp = lp
+        kl = jax.lax.dynamic_index_in_dim(pool_k, li, 0, keepdims=False)
+        vl = jax.lax.dynamic_index_in_dim(pool_v, li, 0, keepdims=False)
+        h, kl, vl = attention(lp["attn"], Lyr.norm(cfg, lp["ln1"], x), kl, vl,
+                              cfg.window_pattern[0])
+        x = x + cfg.residual_scale * h
+        x = x + cfg.residual_scale * Lyr.mlp(cfg, lp["mlp"],
+                                             Lyr.norm(cfg, lp["ln2"], x))
+        pool_k = jax.lax.dynamic_update_index_in_dim(pool_k, kl, li, 0)
+        pool_v = jax.lax.dynamic_update_index_in_dim(pool_v, vl, li, 0)
+        return (x, pool_k, pool_v), None
+
+    layers = jax.tree.map(lambda a: a[:, 0], _group(params["layers"], cfg.num_layers, 1))
+    (x, nk, nv), _ = jax.lax.scan(
+        gbody, (x, pools["k"], pools["v"]), (jnp.arange(cfg.num_layers), layers))
+    return model._logits(params, x)[:, 0], {"k": nk, "v": nv}
+
+
+HEAD_SIZES = {"hd64": ("minicpm_2b", 64), "hd128": ("qwen3_4b", 128)}
+
+
+@pytest.mark.parametrize("heads", list(HEAD_SIZES))
+def test_pool_rows_hold_the_head_layout(heads):
+    """A pool row is a position's heads side by side: the prefill's scatter,
+    the decode step's append and its gather give bitwise the pool contents
+    and logits of pools laid out ``(…, Hkv, hd)``, at heads of 64 (MHA) and
+    of 128 (GQA)."""
+    from repro.runtime import write_prefill_blocks
+
+    arch, hd = HEAD_SIZES[heads]
+    cfg = get_config(arch, smoke=True).replace(head_dim=hd)
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    L, bs, num_blocks = cfg.num_layers, 16, 9
+    heads5 = (L, num_blocks, bs, cfg.num_kv_heads, hd)
+    flat = (L, num_blocks, bs, cfg.kv_dim)
+    # a prefill of two prompts of 32 positions into blocks 1-4
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 32), 0, cfg.vocab_size)
+    ptable = jnp.array([[1, 2], [3, 4]], jnp.int32)
+    _, k_all, v_all = model.prefill_kv(params, {"tokens": tokens})
+    zero = jnp.zeros(flat, jnp.bfloat16)
+    pk, pv = write_prefill_blocks(zero, zero, k_all, v_all, ptable)
+    k5 = k_all.reshape(L, 2, 2, bs, cfg.num_kv_heads, hd)
+    want_k = jnp.zeros(heads5, jnp.bfloat16).at[:, ptable].set(k5)
+    np.testing.assert_array_equal(pk.reshape(heads5), want_k)
+    # then one decode step of those two slots and a third, and a pad slot
+    kk = jax.random.PRNGKey(1)
+    pools = {"k": pk.at[:, 5:].set(jax.random.normal(kk, (L, 4, bs, cfg.kv_dim),
+                                                     jnp.bfloat16)),
+             "v": pv.at[:, 5:].set(-1.0)}
+    table = jnp.array([[1, 2, 7], [3, 4, 8], [5, 6, SCRATCH_BLOCK],
+                       [SCRATCH_BLOCK] * 3], jnp.int32)
+    positions = jnp.array([32, 20, 17, 0], jnp.int32)
+    token = jnp.array([3, 7, 11, 0], jnp.int32)
+    logits, got = jax.jit(model.decode_step_paged)(
+        params, pools, table, positions, token)
+    want_logits, want = jax.jit(
+        lambda *a: _decode_head_layout_oracle(model, *a))(
+        params, {n: a.reshape(heads5) for n, a in pools.items()}, table,
+        positions, token)
+    np.testing.assert_array_equal(logits, want_logits)
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(got[n].reshape(heads5), want[n])
+
+
+@pytest.mark.parametrize("heads", list(HEAD_SIZES))
+def test_serve_stats_count_the_pool_bytes(heads):
+    """``ServeStats`` holds the pools' device bytes and positions as the
+    scheduler built them: on the CPU, with no padding, K and V of every
+    layer at every position, 2 bytes an element."""
+    arch, hd = HEAD_SIZES[heads]
+    cfg = get_config(arch, smoke=True).replace(head_dim=hd)
+    model = Model(cfg)
+    sched = ServeScheduler(model, model.init(jax.random.PRNGKey(0)), capacity=2,
+                           block_size=16, max_total_len=32, num_blocks=9)
+    _, stats = sched.run(_trace(cfg, n=2))
+    assert stats.kv_pool_positions == 9 * 16
+    assert stats.kv_pool_bytes == (cfg.num_layers * 2 * cfg.num_kv_heads * hd
+                                   * 2 * stats.kv_pool_positions)
+
+
 def test_paged_cache_geometry(smoke_model):
     cfg, _, _ = smoke_model
     kv = PagedKVCache(cfg, num_blocks=6, block_size=16)
-    assert kv.k.shape == (cfg.num_layers, 6, 16, cfg.num_kv_heads, cfg.head_dim)
+    assert kv.k.shape == (cfg.num_layers, 6, 16, cfg.num_kv_heads * cfg.head_dim)
     assert kv.k.dtype == jnp.bfloat16
     assert kv.blocks_for(1) == 1 and kv.blocks_for(16) == 1
     assert kv.blocks_for(17) == 2
